@@ -12,6 +12,9 @@ Self-spawning: ``run()`` re-execs this module in a subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the 8-way mesh
 exists regardless of how many devices the invoking process sees — the
 bench works from any CI step (or a dev laptop) without env gymnastics.
+The child runs with ``JAX_PLATFORMS=cpu``: what it measures is a byte
+count, and an accelerator belongs to the parent process that may already
+hold it.
 """
 from __future__ import annotations
 
@@ -59,6 +62,7 @@ def _child(scale: float) -> None:
 def run(scale: float = 1.0, **_) -> list[tuple]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={N_SHARDS}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [str(REPO / "src"), env.get("PYTHONPATH", "")])
     proc = subprocess.run(

@@ -33,9 +33,9 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from .frontier import segment_or
 from .graph import INF, Graph
 from .labelling import LabellingScheme, meta_apsp

@@ -103,7 +103,6 @@ class FrontierEngine:
         n_edges: int,
         block_size: int = 0,
         use_pallas: bool = False,
-        interpret: bool = True,
     ):
         if backend not in BACKENDS:
             raise ValueError(f"unknown backend {backend!r}; expected {BACKENDS}")
@@ -113,7 +112,6 @@ class FrontierEngine:
         self.n_edges = n_edges
         self.block_size = block_size
         self.use_pallas = use_pallas
-        self.interpret = interpret
 
     # -- pytree protocol -----------------------------------------------------
 
@@ -121,15 +119,14 @@ class FrontierEngine:
         keys = tuple(sorted(self.arrays))
         children = tuple(self.arrays[k] for k in keys)
         aux = (keys, self.backend, self.n_vertices, self.n_edges,
-               self.block_size, self.use_pallas, self.interpret)
+               self.block_size, self.use_pallas)
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        keys, backend, n_v, n_e, block, pallas, interp = aux
+        keys, backend, n_v, n_e, block, pallas = aux
         return cls(dict(zip(keys, children)), backend=backend, n_vertices=n_v,
-                   n_edges=n_e, block_size=block, use_pallas=pallas,
-                   interpret=interp)
+                   n_edges=n_e, block_size=block, use_pallas=pallas)
 
     # -- the one operation ---------------------------------------------------
 
@@ -214,8 +211,7 @@ class FrontierEngine:
         f_h = f[:, hub_ids]
         if self.use_pallas:
             from ..kernels.frontier import bitmap_expand_packed
-            next_h = bitmap_expand_packed(f_h, adj_words, n_cols=h,
-                                          interpret=self.interpret)
+            next_h = bitmap_expand_packed(f_h, adj_words, n_cols=h)
         else:
             next_h = _dense_or_matmul(f_h, unpack_bits(adj_words, h))
         return out.at[:, hub_ids].set(out[:, hub_ids] | next_h)
@@ -331,7 +327,6 @@ def make_relay(
     n_hubs: int | None = None,
     block_size: int = 0,
     use_pallas: bool | None = None,
-    interpret: bool | None = None,
 ) -> FrontierEngine:
     """Build a ``FrontierEngine`` for ``graph``.
 
@@ -390,10 +385,8 @@ def make_relay(
         arrays["tail_dst"] = jnp.asarray(dst_np[keep_tail])
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return FrontierEngine(arrays, backend=backend, n_vertices=v, n_edges=e,
-                          use_pallas=bool(use_pallas), interpret=bool(interpret))
+                          use_pallas=bool(use_pallas))
 
 
 def abstract_engine(n_vertices: int, n_edges: int, *,

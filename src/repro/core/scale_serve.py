@@ -29,9 +29,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from .frontier import segment_or
 from .graph import INF, Graph
 from .labelling import LabellingScheme

@@ -229,114 +229,112 @@ def reverse_search(ctx: SearchContext, depth_u, depth_v, d_minus, n_vertices: in
 # ---------------------------------------------------------------------------
 # Stage 3: recover search  (Alg. 4 lines 18-24)
 # ---------------------------------------------------------------------------
+#
+# Every certificate below is a per-landmark test OR-ed (or min-ed) over the
+# landmarks, so recover walks the landmarks one at a time and keeps only
+# (V,) / (E,) state per query.  The (V, R) / (E, R) forms of the same tests
+# do not fit a chip at deployment size: vmapped over a 32-query chunk, one
+# (E, R) int32 temporary of a 6.6M-slot graph is 17 GB before tiling.
 
-def _side_attach(ctx: SearchContext, depth, side_land, n_vertices: int, max_chain: int):
-    """Component (i)/(ii): edges of landmark-free shortest t->r paths for
-    every sketch edge (r, t), vectorized over all landmarks r at once.
 
-    Returns (edge_mask, on) where on[x, r] certifies x on such a path.
-    """
-    ld = widen_dist(ctx.label_dist)
-    lvalid = ld < INF
-    sigma = side_land  # (R,)
+def _label_col(ctx: SearchContext, k):
+    """(V,) int32 labels of landmark ``k`` (traced), widened from the packed
+    column."""
+    return widen_dist(
+        jax.lax.dynamic_index_in_dim(ctx.label_dist, k, axis=1, keepdims=False))
 
+
+def _side_attach(ctx: SearchContext, depth, sigma, ld, dec, k, max_chain: int):
+    """Component (i)/(ii) for landmark ``k``: edges of landmark-free shortest
+    t->r_k paths for the sketch edge (r_k, t) of weight ``sigma``.  ``ld``
+    is the (V,) label column of r_k and ``dec`` the (E,) G- edges along
+    which it decrements (``ld[dst] == ld[src] - 1``)."""
     # Pointwise certificate: G- BFS prefix + label suffix == sigma.
-    on = (
-        lvalid
-        & (depth[:, None] < INF)
-        & (sigma[None, :] < INF)
-        & (depth[:, None] + ld == sigma[None, :])
-    )
+    on = (ld < INF) & (depth < INF) & (sigma < INF) & (depth + ld == sigma)
 
     # Anchor-chain closure for path segments beyond the explored ball
-    # (paper's Z-walk): extend along label-decrement edges in G-.
+    # (paper's Z-walk): extend along label-decrement edges in G-.  The
+    # label-decrement coupling ties src and dst, so this is a generic
+    # per-edge message, not a vertex-value relay.
     def cond(c):
         _, changed, it = c
         return changed & (it < max_chain)
 
     def body(c):
         on, _, it = c
-        # label-decrement coupling ties src and dst per landmark, so this is
-        # a generic per-edge message, not a vertex-value relay
-        msgs = (
-            ctx.gminus_e[:, None]
-            & on[ctx.src]
-            & lvalid[ctx.dst]
-            & (ld[ctx.dst] == ld[ctx.src] - 1)
-        )
-        grown = ctx.engine.scatter(msgs.T).T
-        new_on = on | grown
-        changed = jnp.any(new_on & ~on)
-        return new_on, changed, it + 1
+        new_on = on | ctx.engine.scatter(dec & on[ctx.src])
+        return new_on, jnp.any(new_on & ~on), it + 1
 
     t = jnp.any(on)
     on, _, _ = jax.lax.while_loop(cond, body, (on, t | ~t, t.astype(jnp.int32) * 0))
 
-    # Interior edges: both endpoints certified, label distance decrements.
-    interior = ctx.gminus_e & jnp.any(
-        on[ctx.src] & on[ctx.dst] & (ld[ctx.dst] == ld[ctx.src] - 1), axis=1
-    )
-
-    # Final hops into the landmark (both orientations of the same edge).
-    def hop(edge_end, other_end):
-        r_idx = jnp.clip(ctx.lid[edge_end], 0, None)
-        valid = ctx.is_landmark[edge_end]
-        on_o = jnp.take_along_axis(on[other_end], r_idx[:, None], axis=1)[:, 0]
-        ld_o = jnp.take_along_axis(ld[other_end], r_idx[:, None], axis=1)[:, 0]
-        return valid & on_o & (ld_o == 1)
-
-    hops = hop(ctx.dst, ctx.src) | hop(ctx.src, ctx.dst)
-    return interior | hops, on
-
-
-def _delta_edges(ctx: SearchContext, meta_edge, n_vertices: int):
-    """Component (iii): edges on landmark-free shortest r_i - r_j paths for
-    every meta edge in the sketch (the paper's precomputed Delta), derived
-    from labels alone via a min-plus contraction.
-
-    For a G- edge (x, y):  on path iff  exists (i,j) in sketch meta edges:
-        ld[x,i] + 1 + ld[y,j] == w[i,j]
-    By the triangle inequality ld[x,i] + ld[y,j] - w[i,j] >= -1, so the
-    existential test is  min_{i,j} masked(ld[x,i] + ld[y,j] - w[i,j]) == -1.
-    """
-    ld = widen_dist(ctx.label_dist)
-    w = widen_dist(ctx.meta_w)
-    fin = (w < INF) & meta_edge
-
-    # T[x, i] = min_j ( ld[x, j] + (-w[i, j] | INF) )
-    m2 = jnp.where(fin, -w, INF).T.astype(jnp.int32)        # (j, i)
-    t = jnp.min(ld[:, :, None] + m2[None, :, :], axis=1)    # (V, R_i)
-    minval = jnp.min(ld[ctx.src] + t[ctx.dst], axis=1)      # (E,)
-    interior = ctx.gminus_e & (minval == -1)
-
-    # Boundary hops r_i -> y (y has ld[y, j] == w[i,j]-1) and x -> r_j.
-    g1 = jnp.where(fin, w - 1, -1)          # (i, j) row-indexed by src landmark
-    h1 = jnp.where(fin, w - 1, -1).T        # (j, i) row-indexed by dst landmark
-
-    def hop(end_land, end_other, table):
-        r_idx = jnp.clip(ctx.lid[end_land], 0, None)
-        valid = ctx.is_landmark[end_land] & ~ctx.is_landmark[end_other]
-        targets = table[r_idx]              # (E, R)
-        match = jnp.any(ld[end_other] == targets, axis=1)
-        return valid & match
-
-    hops = hop(ctx.src, ctx.dst, g1) | hop(ctx.dst, ctx.src, h1)
-
-    # Direct landmark-landmark sketch edges of weight 1.
-    both = ctx.is_landmark[ctx.src] & ctx.is_landmark[ctx.dst]
-    i_idx = jnp.clip(ctx.lid[ctx.src], 0, None)
-    j_idx = jnp.clip(ctx.lid[ctx.dst], 0, None)
-    direct = both & meta_edge[i_idx, j_idx] & (w[i_idx, j_idx] == 1)
-
-    return interior | hops | direct
+    # Interior edges (both endpoints certified, label decrements) and the
+    # final hops into r_k, in both orientations of the same edge.
+    interior = dec & on[ctx.src] & on[ctx.dst]
+    hop_in = (ctx.lid[ctx.dst] == k) & on[ctx.src] & (ld[ctx.src] == 1)
+    hop_out = (ctx.lid[ctx.src] == k) & on[ctx.dst] & (ld[ctx.dst] == 1)
+    return interior | hop_in | hop_out
 
 
 def recover_search(ctx: SearchContext, q: Query, depth_u, depth_v,
-                   n_vertices: int, max_chain: int):
-    e_u, _ = _side_attach(ctx, depth_u, q.du_land, n_vertices, max_chain)
-    e_v, _ = _side_attach(ctx, depth_v, q.dv_land, n_vertices, max_chain)
-    e_m = _delta_edges(ctx, q.meta_edge, n_vertices)
-    return e_u | e_v | e_m
+                   max_chain: int):
+    """Components (i)/(ii) on both sides plus (iii): edges on landmark-free
+    shortest r_i - r_j paths for every meta edge in the sketch (the
+    paper's precomputed Delta), derived from labels alone.
+
+    For a G- edge (x, y), (iii) holds iff some sketch meta edge (i, j) has
+        ld[x,i] + 1 + ld[y,j] == w[i,j]
+    By the triangle inequality ld[x,i] + ld[y,j] - w[i,j] >= -1, so the
+    existential test is  min_{i,j} masked(ld[x,i] + ld[y,j] - w[i,j]) == -1,
+    taken one landmark i at a time through
+        t_i[y] = min_j ( ld[y, j] + (-w[i, j] | INF) ).
+    """
+    r = ctx.label_dist.shape[1]
+    w = widen_dist(ctx.meta_w)
+    fin = (w < INF) & q.meta_edge
+    m2 = jnp.where(fin, -w, INF).astype(jnp.int32)   # (i, j)
+    g1 = jnp.where(fin, w - 1, -1)                   # (i, j)
+    lid_src = jnp.clip(ctx.lid[ctx.src], 0, None)
+    lid_dst = jnp.clip(ctx.lid[ctx.dst], 0, None)
+    # loop carries start from query data so that, under a shard_map, their
+    # varying-axes type matches the per-query values they accumulate
+    zero = q.u * 0
+    big = jnp.int32(1 << 30) + zero   # above any finite or INF-saturated sum
+
+    def per_landmark(k, c):
+        edges, minval, hop_a, hop_b = c
+        ld = _label_col(ctx, k)
+        ls, ldd = ld[ctx.src], ld[ctx.dst]
+        dec = ctx.gminus_e & (ldd < INF) & (ldd == ls - 1)
+        edges = edges | _side_attach(ctx, depth_u, q.du_land[k], ld, dec, k,
+                                     max_chain)
+        edges = edges | _side_attach(ctx, depth_v, q.dv_land[k], ld, dec, k,
+                                     max_chain)
+
+        # (iii) interior, landmark i = k
+        def t_step(j, t):
+            return jnp.minimum(t, _label_col(ctx, j) + m2[k, j])
+
+        t_k = jax.lax.fori_loop(0, r, t_step, jnp.full(ld.shape, big))
+        minval = jnp.minimum(minval, ls + t_k[ctx.dst])
+        # (iii) boundary hops r_i -> y (ld[y, j] == w[i,j] - 1) and
+        # x -> r_j, column j = k
+        hop_a = hop_a | (ldd == g1[lid_src, k])
+        hop_b = hop_b | (ls == g1[k, lid_dst])
+        return edges, minval, hop_a, hop_b
+
+    false_e = ctx.gminus_e & (zero != 0)
+    init = (false_e, jnp.full(ctx.src.shape, big), false_e, false_e)
+    edges, minval, hop_a, hop_b = jax.lax.fori_loop(0, r, per_landmark, init)
+
+    lm_src = ctx.is_landmark[ctx.src]
+    lm_dst = ctx.is_landmark[ctx.dst]
+    interior = ctx.gminus_e & (minval == -1)
+    hops = (lm_src & ~lm_dst & hop_a) | (lm_dst & ~lm_src & hop_b)
+    # Direct landmark-landmark sketch edges of weight 1.
+    direct = lm_src & lm_dst & q.meta_edge[lid_src, lid_dst] & (
+        w[lid_src, lid_dst] == 1)
+    return edges | interior | hops | direct
 
 
 # ---------------------------------------------------------------------------
@@ -358,7 +356,7 @@ def guided_search(ctx: SearchContext, q: Query, n_vertices: int,
     recover_on = (q.d_top < INF) & (q.d_top <= d_minus)
 
     e_rev = reverse_search(ctx, depth_u, depth_v, d_minus, n_vertices)
-    e_rec = recover_search(ctx, q, depth_u, depth_v, n_vertices, max_chain)
+    e_rec = recover_search(ctx, q, depth_u, depth_v, max_chain)
 
     trivial = q.u == q.v
     edge_mask = ((e_rev & reverse_on) | (e_rec & recover_on)) & ~trivial

@@ -45,9 +45,9 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from .distributed import (
     EdgePartition,
     ShardedLabels,
@@ -296,28 +296,43 @@ def make_sharded_general_step(
                 hop_out = (src_lid == ri)[None] & on[:, dst_l] & (ld_e == 1)[None]
                 rec_edges = rec_edges | interior | hop_in | hop_out
 
-        # ---- E2: Delta edges (fully local) ----------------------------------
+        # ---- E2: Delta edges (fully local), one landmark i at a time -------
+        # min_{i,j} ld[x,i] + ld[y,j] - w[i,j] == -1 through the per-vertex
+        # t_i[y] = min_j ld[y,j] + (-w[i,j] | INF), as in search.recover_search:
+        # the (E, R, R) per-query form does not fit a chip at real size
         meta_w32 = widen_dist(meta_w_p)
         w32 = jnp.where(meta_w32 < INF, meta_w32, INF)
+        fin = sk.meta_edge & (meta_w32 < INF)[None]              # (B, R, R)
+        m2 = jnp.where(fin, -w32[None], INF).astype(jnp.int32)   # (B, i, j)
+        g1 = jnp.where(fin, w32[None] - 1, -1)                   # (B, i, j)
+        sl = jnp.clip(src_lid, 0)
+        dl = jnp.clip(dst_lid, 0)
+        labels_pad = jnp.concatenate(
+            [labels_loc, jnp.full((1, r), INF, jnp.int32)], axis=0)
+        big = jnp.int32(1 << 30) + (vst * 0)   # above any sum; shard-varying
 
-        def delta_b(bi, acc):
-            me = sk.meta_edge[bi]
-            fin = me & (meta_w32 < INF)
-            m2 = jnp.where(fin, -w32, INF).T.astype(jnp.int32)
-            t1 = jnp.min(label_dst[:, :, None] + m2[None], axis=1)
-            minval = jnp.min(label_src32 + t1, axis=1)
-            interior = gm_e & (minval == -1)
-            g1 = jnp.where(fin, w32 - 1, -1)
-            hop1 = (src_lid >= 0) & (
-                label_dst == g1[jnp.clip(src_lid, 0)]).any(1)
-            hop2 = (dst_lid >= 0) & (
-                label_src32 == g1.T[jnp.clip(dst_lid, 0)]).any(1)
-            direct = (src_lid >= 0) & (dst_lid >= 0) & fin[
-                jnp.clip(src_lid, 0), jnp.clip(dst_lid, 0)] & (
-                w32[jnp.clip(src_lid, 0), jnp.clip(dst_lid, 0)] == 1)
-            return acc.at[bi].set(interior | hop1 | hop2 | direct)
+        def delta_i(i, c):
+            minval, hop_a, hop_b = c
 
-        delta_edges = jax.lax.fori_loop(0, b, delta_b, false_e)
+            def t_step(j, t):
+                return jnp.minimum(
+                    t, labels_pad[:, j][None, :] + m2[:, i, j][:, None])
+
+            t_i = jax.lax.fori_loop(0, r, t_step,
+                                    jnp.full((b, vloc + 1), big))
+            minval = jnp.minimum(minval, label_src32[:, i][None, :]
+                                 + t_i[:, dst_l])
+            hop_a = hop_a | (label_dst[:, i][None, :] == g1[:, sl, i])
+            hop_b = hop_b | (label_src32[:, i][None, :] == g1[:, i, dl])
+            return minval, hop_a, hop_b
+
+        minval, hop_a, hop_b = jax.lax.fori_loop(
+            0, r, delta_i, (jnp.full(false_e.shape, big), false_e, false_e))
+        delta_edges = ((gm_e[None] & (minval == -1))
+                       | ((src_lid >= 0)[None] & hop_a)
+                       | ((dst_lid >= 0)[None] & hop_b)
+                       | ((src_lid >= 0) & (dst_lid >= 0))[None]
+                       & fin[:, sl, dl] & (w32[sl, dl] == 1)[None])
 
         edge_mask = ((rev_edges & reverse_on[:, None])
                      | ((rec_edges | delta_edges) & recover_on[:, None]))
@@ -495,14 +510,20 @@ def make_sharded_onesided_step(
             alive = jnp.where(act, row_new, alive)
             return jnp.where(new, level + 1, depth), level + 1, alive
 
-        zero = jnp.int32(0) + (vst * 0)
+        # the level and row-liveness carries vary per shard (they mix with
+        # shard-local depths), so they start varying too
+        zero, alive0 = jax.lax.pcast(
+            (jnp.int32(0), roots == roots), axis_names, to="varying")
         depth, _, _ = jax.lax.while_loop(
-            cond, step, (depth0, zero, roots == roots))
+            cond, step, (depth0, zero, alive0))
 
         cert = (to_lm_src + 1 + depth[:, dst_l]) == d[:, None]
         cert = cert & (d < INF)[:, None] & (dst_l < vloc)[None, :]
         mask = _scatter_symmetrize(cert, eid_l, rev_l, n_edges, axis_names)
-        return mask, d
+        # d is equal on every shard, but read from an all_gather, whose
+        # result the vma check types as varying: pmax (exact on equal
+        # values) types it replicated for the out_spec
+        return mask, jax.lax.pmax(d, axis_names)
 
     return jax.jit(
         shard_map(

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .calling import interpret_mode, match_vma
+
 
 def _expand_kernel(f_ref, a_ref, o_ref, acc_ref, *, k_grid: int):
     @pl.when(pl.program_id(2) == 0)
@@ -59,11 +61,13 @@ def bitmap_expand(
     tm: int = 8,
     tn: int = 128,
     tk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """next[r, w] = any_v frontier[r, v] & adjacency[v, w].
 
     frontier (R, V) bool; adjacency (V, W) bool -> (R, W) bool.
+    ``interpret=None`` compiles the kernel on a TPU and interprets it on
+    every other backend.
     """
     if frontier.ndim != 2 or adjacency.ndim != 2:
         raise ValueError("rank-2 inputs required")
@@ -75,6 +79,7 @@ def bitmap_expand(
     a = _pad_to(_pad_to(adjacency.astype(jnp.float32), tk, 0), tn, 1)
     k_grid = f.shape[1] // tk
     grid = (f.shape[0] // tm, a.shape[1] // tn, k_grid)
+    (f, a), vma = match_vma(f, a)
 
     out = pl.pallas_call(
         functools.partial(_expand_kernel, k_grid=k_grid),
@@ -84,9 +89,10 @@ def bitmap_expand(
             pl.BlockSpec((tk, tn), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((f.shape[0], a.shape[1]), jnp.bool_),
+        out_shape=jax.ShapeDtypeStruct((f.shape[0], a.shape[1]), jnp.bool_,
+                                       vma=vma),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret, vma),
     )(f, a)
     return out[:r, :v]
 
@@ -101,7 +107,8 @@ def _expand_packed_kernel(f_ref, w_ref, o_ref, acc_ref, *, k_grid: int):
     # dense mask exists only here, per tile — HBM holds the words.
     words = w_ref[...]
     bits = (words[:, :, None] >> jnp.arange(32, dtype=jnp.uint32)) & jnp.uint32(1)
-    a = bits.reshape(words.shape[0], -1).astype(jnp.float32)
+    # via int32: Mosaic has no uint32 -> float32 cast (bits are 0/1)
+    a = bits.reshape(words.shape[0], -1).astype(jnp.int32).astype(jnp.float32)
     acc_ref[...] += jnp.dot(
         f_ref[...], a, preferred_element_type=jnp.float32
     )
@@ -112,16 +119,15 @@ def _expand_packed_kernel(f_ref, w_ref, o_ref, acc_ref, *, k_grid: int):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_cols", "tm", "tn", "tk", "interpret"))
+                   static_argnames=("n_cols", "tm", "tk", "interpret"))
 def bitmap_expand_packed(
     frontier: jax.Array,
     adj_words: jax.Array,
     *,
     n_cols: int | None = None,
     tm: int = 8,
-    tn: int = 128,
     tk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """``bitmap_expand`` over a *bit-packed* adjacency: frontier (R, V)
     bool x adj_words (V, W) uint32 (32 little-endian columns per word,
@@ -130,20 +136,25 @@ def bitmap_expand_packed(
     The adjacency never materializes densely in HBM: each grid step loads a
     uint32 word tile and unpacks it in VMEM right before the OR-AND matmul,
     so the hub-hub reachability rows stay 32x smaller end-to-end.
+
+    The word tile spans all words of a row up to 128 of them (4,096
+    columns), and 128-word blocks beyond: a TPU block's last dimension must
+    be the whole array's or a multiple of 128.  ``interpret=None`` compiles
+    on a TPU and interprets elsewhere.
     """
     if frontier.ndim != 2 or adj_words.ndim != 2:
         raise ValueError("rank-2 inputs required")
     if frontier.shape[1] != adj_words.shape[0]:
         raise ValueError(f"bad shapes {frontier.shape} x {adj_words.shape}")
-    if tn % 32:
-        raise ValueError("tn must be a multiple of the 32-bit word width")
     r = frontier.shape[0]
     n = adj_words.shape[1] * 32 if n_cols is None else n_cols
-    tw = tn // 32
+    tw = min(adj_words.shape[1], 128)
+    tn = tw * 32
     f = _pad_to(_pad_to(frontier.astype(jnp.float32), tm, 0), tk, 1)
     w = _pad_to(_pad_to(adj_words, tk, 0), tw, 1)
     k_grid = f.shape[1] // tk
     grid = (f.shape[0] // tm, w.shape[1] // tw, k_grid)
+    (f, w), vma = match_vma(f, w)
 
     out = pl.pallas_call(
         functools.partial(_expand_packed_kernel, k_grid=k_grid),
@@ -154,8 +165,8 @@ def bitmap_expand_packed(
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((f.shape[0], w.shape[1] * 32),
-                                       jnp.bool_),
+                                       jnp.bool_, vma=vma),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret, vma),
     )(f, w)
     return out[:r, :n]

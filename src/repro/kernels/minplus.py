@@ -2,8 +2,8 @@
 
 Sketching (Eq. 3) is a min-plus contraction (B, R) x (R, R) -> (B, R).  The
 MXU multiplies-and-adds and cannot evaluate a (min, +) semiring, so this
-kernel targets the **VPU**: 8x128-aligned VMEM tiles, a fori_loop over the
-contraction dim broadcasting one A-column + one B-row per step, and a
+kernel targets the **VPU**: 8x128-aligned VMEM tiles, an unrolled loop over
+the contraction dim broadcasting one A-column + one B-row per step, and a
 running elementwise minimum held in registers/VMEM.  This is the honest TPU
 mapping of the paper's nested landmark-pair loop (Algorithm 3, lines 2-5):
 arithmetic intensity is O(K) per output element, so for K = |R| = 20..128
@@ -22,18 +22,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .calling import interpret_mode, match_vma
+
 
 def _minplus_kernel(a_ref, b_ref, o_ref, *, k_steps: int):
-    a = a_ref[...]  # (TM, K)
-    b = b_ref[...]  # (K, TN)
-
-    def body(k, acc):
-        col = jax.lax.dynamic_slice_in_dim(a, k, 1, axis=1)  # (TM, 1)
-        row = jax.lax.dynamic_slice_in_dim(b, k, 1, axis=0)  # (1, TN)
-        return jnp.minimum(acc, col + row)
-
-    init = a[:, 0:1] + b[0:1, :]
-    o_ref[...] = jax.lax.fori_loop(1, k_steps, body, init)
+    # K is static, so the contraction unrolls over static ref slices: Mosaic
+    # has no lowering for a traced ``dynamic_slice``, and cannot prove a
+    # traced lane offset (``pl.ds(k, 1)``) 128-aligned.  Only the k_steps
+    # real columns are visited; the INF-safe padding beyond them could
+    # never win the minimum.
+    acc = a_ref[:, 0:1] + b_ref[0:1, :]        # (TM, 1) + (1, TN)
+    for k in range(1, k_steps):
+        acc = jnp.minimum(acc, a_ref[:, k:k + 1] + b_ref[k:k + 1, :])
+    o_ref[...] = acc
 
 
 def _pad_to(x: jax.Array, m: int, axis: int, fill) -> jax.Array:
@@ -53,12 +54,12 @@ def minplus(
     *,
     tm: int = 128,
     tn: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """C[m, n] = min_k (A[m, k] + B[k, n]) with INF-safe padding.
 
-    ``interpret=True`` executes the kernel body on CPU for validation; on a
-    real TPU pass ``interpret=False``.
+    ``interpret=None`` decides from the backend the call is traced for:
+    compiled on a TPU, the Pallas interpreter everywhere else.
     """
     if a.ndim != 2 or b.ndim != 2 or a.shape[1] != b.shape[0]:
         raise ValueError(f"bad shapes {a.shape} x {b.shape}")
@@ -76,17 +77,19 @@ def minplus(
     ap = _pad_to(_pad_to(a, tm, 0, big), 128, 1, big)
     bp = _pad_to(_pad_to(b, 128, 0, big), tn, 1, big)
     kp = ap.shape[1]
+    (ap, bp), vma = match_vma(ap, bp)
 
     grid = (ap.shape[0] // tm, bp.shape[1] // tn)
     out = pl.pallas_call(
-        functools.partial(_minplus_kernel, k_steps=kp),
+        functools.partial(_minplus_kernel, k_steps=k),
         grid=grid,
         in_specs=[
             pl.BlockSpec((tm, kp), lambda i, j: (i, 0)),
             pl.BlockSpec((kp, tn), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((tm, tn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((ap.shape[0], bp.shape[1]), a.dtype),
-        interpret=interpret,
+        out_shape=jax.ShapeDtypeStruct((ap.shape[0], bp.shape[1]), a.dtype,
+                                       vma=vma),
+        interpret=interpret_mode(interpret, vma),
     )(ap, bp)
     return out[:m, :n]

@@ -1,0 +1,28 @@
+"""Where the entry points keep JAX's persistent compilation cache.
+
+JAX reads ``JAX_COMPILATION_CACHE_DIR`` itself; where it is set, that
+directory is used and no other.  Where it is not, the cache goes to a
+fixed ``.jax_cache/`` at the checkout root: the path is part of the cache
+key, so a directory that moved between runs would never hit.
+
+A function the entry points call, never an import-time side effect.
+"""
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
+DEFAULT_DIR = CHECKOUT_ROOT / ".jax_cache"
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at its directory; returns
+    the path in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
+    return str(DEFAULT_DIR)

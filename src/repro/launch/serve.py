@@ -30,6 +30,7 @@ from ..core import (
     packed_size_bytes,
     ring_of_cliques,
 )
+from .compile_cache import configure_compile_cache
 
 
 def build_graph(kind: str, n: int, seed: int):
@@ -62,6 +63,7 @@ def main() -> None:
                          "(0 = pick an ephemeral port); implies at least "
                          "one streaming replica")
     args = ap.parse_args()
+    print(f"[serve] compilation cache: {configure_compile_cache()}")
 
     g = build_graph(args.graph, args.n, args.seed)
     print(f"[serve] graph {args.graph}: V={g.n_vertices} E={g.n_edges // 2}")

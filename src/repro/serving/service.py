@@ -30,7 +30,7 @@ The service owns *how* a planned batch runs; the planner owns *what* runs
 * **Multi-device.**  With ``mesh=`` (or ``devices=``), general-lane chunks
   run batch-sharded across local devices through
   ``core.distributed.make_serve_step`` (replicated graph/labels, queries
-  split over the mesh via ``repro.compat.shard_map``), then re-enter the
+  split over the mesh via ``jax.shard_map``), then re-enter the
   shared symmetrization program.  Landmark lanes stay single-device: they
   are label lookups plus one bounded BFS, never the serving bottleneck.
 

@@ -4,7 +4,7 @@ import threading
 
 import jax
 
-from repro.compat import shard_map              # the blessed QBS001 route
+from jax import shard_map                       # the QBS001-clean route
 
 
 def make_step(fn, mesh):

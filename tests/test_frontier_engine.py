@@ -91,8 +91,7 @@ def test_hybrid_pallas_kernel_path():
     rng = np.random.default_rng(1)
     vals = jnp.asarray(rng.random((4, g.n_vertices)) < 0.3)
     ref = make_relay(g, backend="hybrid", n_hubs=16, use_pallas=False)
-    pal = make_relay(g, backend="hybrid", n_hubs=16, use_pallas=True,
-                     interpret=True)
+    pal = make_relay(g, backend="hybrid", n_hubs=16, use_pallas=True)
     assert (np.asarray(pal.relay(vals)) == np.asarray(ref.relay(vals))).all()
 
 

@@ -39,7 +39,7 @@ def test_collective_parse_real_program():
     """psum under shard_map must show up as all-reduce bytes."""
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("x",))
 

@@ -164,7 +164,7 @@ def test_async_checkpoint(tmp_path):
 def test_compressed_psum_shard_map():
     """bf16/int8-EF psum == exact psum within tolerance on a 1-dev mesh."""
     from jax.sharding import Mesh
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.distributed import psum_bf16, psum_int8_ef
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))

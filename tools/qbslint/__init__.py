@@ -2,15 +2,15 @@
 
 The paper's exactness guarantee survives only while every layer of this
 repo preserves a handful of invariants that plain pytest cannot see
-until they are already broken at runtime: all ``shard_map`` goes
-through ``repro.compat`` (ROADMAP standing constraint), serving time
+until they are already broken at runtime: all ``shard_map`` is the
+vma-checked ``jax.shard_map``, serving time
 flows only through the injectable clock (DESIGN.md §8), cache inserts
 go only through ``ServingService.cache_put``, and ``StreamingService``
 state is ``_lock``-guarded across timer threads.  qbslint turns each of
 those conventions into a machine-checked rule over the stdlib ``ast``:
 
 =======  ==============================================================
-QBS001   ``shard_map`` imported/used outside ``src/repro/compat.py``
+QBS001   ``jax.experimental.shard_map`` imported/used
 QBS002   wall-clock (``time.time``/``monotonic``/``sleep``,
          ``threading.Timer``) in ``serving/`` outside ``clock.py``
 QBS003   host-sync calls (``.item()``, ``int()``/``float()`` on

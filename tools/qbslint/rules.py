@@ -74,22 +74,18 @@ class Rule:
 
 
 # ---------------------------------------------------------------------------
-# QBS001 — shard_map only via repro.compat
+# QBS001 — shard_map only as jax.shard_map
 # ---------------------------------------------------------------------------
 
 
-class ShardMapViaCompat(Rule):
+class ExperimentalShardMap(Rule):
     id = "QBS001"
-    summary = ("jax shard_map imported/used outside compat.py — route it "
-               "through repro.compat.shard_map (owns check_rep=False on "
-               "the 0.4.x experimental API)")
-    _TARGETS = {"jax.shard_map", "jax.experimental.shard_map"}
-    _MSG = ("direct shard_map use; import it from repro.compat instead "
-            "(ROADMAP standing constraint: the shim owns the 0.4.x "
-            "check_rep/API-drift handling)")
-
-    def applies(self, path: str) -> bool:
-        return not path.endswith("compat.py")
+    summary = ("experimental shard_map imported/used — use jax.shard_map "
+               "(vma-checked; the experimental module is the retired 0.4.x "
+               "API)")
+    _TARGETS = {"jax.experimental.shard_map"}
+    _MSG = ("experimental shard_map; use jax.shard_map, whose varying-axes "
+            "(vma) checks every program here passes")
 
     def check(self, mod: Module) -> Iterable[Finding]:
         aliases = _Aliases(mod.tree)
@@ -103,8 +99,7 @@ class ShardMapViaCompat(Rule):
                 m = node.module or ""
                 names = {a.name for a in node.names}
                 if m == "jax.experimental.shard_map" or \
-                        (m in ("jax", "jax.experimental")
-                         and "shard_map" in names):
+                        (m == "jax.experimental" and "shard_map" in names):
                     yield self.finding(mod, node, self._MSG)
             elif isinstance(node, ast.Attribute):
                 if aliases.resolve(node) in self._TARGETS:
@@ -722,7 +717,7 @@ class TableMutationOutsideEpoch(Rule):
             yield from self._visit(mod, child, allowed)
 
 
-ALL_RULES = (ShardMapViaCompat(), WallClockInServing(), HostSyncInJit(),
+ALL_RULES = (ExperimentalShardMap(), WallClockInServing(), HostSyncInJit(),
              JitInHotPath(), LockDiscipline(), CacheInsertBypass(),
              PackedWidenOnHost(), NoReplicatedGather(),
              TableMutationOutsideEpoch())
