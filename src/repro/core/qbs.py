@@ -66,6 +66,7 @@ from .search import (
     make_search_context,
 )
 from .sketch import compute_sketch_batch
+from ..tracing import scope
 
 
 @dataclass(frozen=True)
@@ -169,12 +170,15 @@ def _landmark_onesided_lanes(engine, lm_dist, src, dst, rev_edge,
     each row bounded at its own d - 1 (those shortest paths may pass
     *through* landmarks, so the G- engine is wrong here — ``engine`` is
     the unmasked full-graph relay)."""
-    to_lm = widen_dist(lm_dist[r_idx])                  # (B, V)
-    d = to_lm[jnp.arange(roots.shape[0]), roots]
-    bounds = jnp.where(d < INF, d - 1, 0)   # disconnected rows never expand
-    depth = bfs_depths_batch(engine, roots, max_levels, bounds=bounds)
-    mask = _certify_spg_edges_batch(src, dst, rev_edge, to_lm, depth, d)
-    return d, mask & (d < INF)[:, None]
+    with scope("qbs.onesided.certify"):
+        to_lm = widen_dist(lm_dist[r_idx])              # (B, V)
+        d = to_lm[jnp.arange(roots.shape[0]), roots]
+    with scope("qbs.onesided.bfs"):
+        bounds = jnp.where(d < INF, d - 1, 0)  # disconnected rows never expand
+        depth = bfs_depths_batch(engine, roots, max_levels, bounds=bounds)
+    with scope("qbs.onesided.certify"):
+        mask = _certify_spg_edges_batch(src, dst, rev_edge, to_lm, depth, d)
+        return d, mask & (d < INF)[:, None]
 
 
 @lru_cache(maxsize=None)
@@ -191,10 +195,11 @@ def _make_search_batch(n_vertices: int, max_levels: int, max_chain: int,
     def search_batch(ctx, label_dist, meta_w, meta_dist, us, vs):
         # gather the *packed* rows from HBM; compute_sketch_batch
         # widens them (and the packed meta tables) in registers
-        lu = label_dist[us]
-        lv = label_dist[vs]
-        sk = compute_sketch_batch(lu, lv, meta_w, meta_dist,
-                                  use_pallas=use_pallas)
+        with scope("qbs.sketch"):
+            lu = label_dist[us]
+            lv = label_dist[vs]
+            sk = compute_sketch_batch(lu, lv, meta_w, meta_dist,
+                                      use_pallas=use_pallas)
         queries = Query(
             u=us, v=vs, d_top=sk.d_top,
             du_land=sk.du_land, dv_land=sk.dv_land,

@@ -33,6 +33,7 @@ import numpy as np
 from .frontier import FrontierEngine, make_relay
 from .graph import INF, Graph
 from .packing import PackedLabels, pack_dist, pack_labelling, widen_dist
+from ..tracing import scope
 
 
 class SearchContext(NamedTuple):
@@ -343,9 +344,10 @@ def recover_search(ctx: SearchContext, q: Query, depth_u, depth_v,
 
 def guided_search(ctx: SearchContext, q: Query, n_vertices: int,
                   max_levels: int = 64, max_chain: int = 64) -> SearchResult:
-    depth_u, depth_v, d_u, d_v, _, _, met = bidirectional_bfs(
-        ctx, q, n_vertices, max_levels
-    )
+    with scope("qbs.bfs"):
+        depth_u, depth_v, d_u, d_v, _, _, met = bidirectional_bfs(
+            ctx, q, n_vertices, max_levels
+        )
 
     common = (depth_u < INF) & (depth_v < INF)
     sums = jnp.where(common, depth_u + depth_v, INF)
@@ -355,8 +357,10 @@ def guided_search(ctx: SearchContext, q: Query, n_vertices: int,
     reverse_on = met & (d_minus <= q.d_top)
     recover_on = (q.d_top < INF) & (q.d_top <= d_minus)
 
-    e_rev = reverse_search(ctx, depth_u, depth_v, d_minus, n_vertices)
-    e_rec = recover_search(ctx, q, depth_u, depth_v, max_chain)
+    with scope("qbs.reverse"):
+        e_rev = reverse_search(ctx, depth_u, depth_v, d_minus, n_vertices)
+    with scope("qbs.recover"):
+        e_rec = recover_search(ctx, q, depth_u, depth_v, max_chain)
 
     trivial = q.u == q.v
     edge_mask = ((e_rev & reverse_on) | (e_rec & recover_on)) & ~trivial

@@ -38,6 +38,8 @@ from typing import Iterator, NamedTuple
 
 import numpy as np
 
+from ..tracing import span
+
 LANE_TRIVIAL = 0
 LANE_LANDMARK_PAIR = 1
 LANE_ONE_SIDED = 2
@@ -136,14 +138,15 @@ def plan_from_pairs(cu: np.ndarray, cv: np.ndarray,
     in-flight state on canonical pairs, so by the time it admits a batch
     the dedup work is already done; ``inv`` is the identity.  ``cls``
     carries the per-pair QoS class lane the scheduler selected from."""
-    cu = np.asarray(cu, np.int32).reshape(-1)
-    cv = np.asarray(cv, np.int32).reshape(-1)
-    lane = classify_lanes(cu, cv, is_landmark)
-    lanes = tuple(np.flatnonzero(lane == k) for k in range(N_LANES))
-    u_cls = None if cls is None else np.asarray(cls, np.int16).reshape(-1)
-    return QueryPlan(n=cu.shape[0], cu=cu, cv=cv,
-                     inv=np.arange(cu.shape[0], dtype=np.intp), lane=lane,
-                     lanes=lanes, cls=u_cls)
+    with span("qbs.planner.plan"):
+        cu = np.asarray(cu, np.int32).reshape(-1)
+        cv = np.asarray(cv, np.int32).reshape(-1)
+        lane = classify_lanes(cu, cv, is_landmark)
+        lanes = tuple(np.flatnonzero(lane == k) for k in range(N_LANES))
+        u_cls = None if cls is None else np.asarray(cls, np.int16).reshape(-1)
+        return QueryPlan(n=cu.shape[0], cu=cu, cv=cv,
+                         inv=np.arange(cu.shape[0], dtype=np.intp), lane=lane,
+                         lanes=lanes, cls=u_cls)
 
 
 def merge_plans(plans: list[QueryPlan],

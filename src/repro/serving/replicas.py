@@ -55,6 +55,7 @@ from typing import Sequence
 
 import numpy as np
 
+from ..tracing import span
 from . import debug
 from .clock import SystemClock
 from .stream import StreamingService
@@ -193,7 +194,7 @@ class ReplicaRouter:
         whole sub-batch."""
         us = np.asarray(us, np.int32).reshape(-1)
         vs = np.asarray(vs, np.int32).reshape(-1)
-        with self._lock:
+        with span("qbs.router.route"), self._lock:
             by_owner: dict[int, list[int]] = {}
             for k, (u, v) in enumerate(zip(us.tolist(), vs.tolist())):
                 i = self._owner_locked((min(u, v), max(u, v)))

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.graph import INF
+from ..tracing import span
 from .planner import (
     LANE_GENERAL,
     LANE_LANDMARK_PAIR,
@@ -66,6 +67,25 @@ from .planner import (
 
 _NO_EDGES = np.zeros((0,), np.int32)   # edge counts fit int32 (E << 2^31)
 _NO_EDGES.flags.writeable = False   # shared by every trivial-lane result
+
+
+def _launch(step: Callable, *host_args):
+    """One chunk's dispatch: its host index arrays to the device, then
+    the lane program, launched without a sync."""
+    with span("qbs.service.dispatch"):
+        return step(*(jnp.asarray(a) for a in host_args))
+
+
+def fetch_chunk(out, stats: dict) -> tuple[np.ndarray, np.ndarray]:
+    """Wait for a dispatched chunk ``(dist, edge_mask)``, then copy it to
+    the host; the copy's bytes add to ``stats["result_bytes"]``."""
+    nbytes = sum(x.nbytes for x in out)
+    with span("qbs.service.device_wait"):
+        jax.block_until_ready(out)
+    with span("qbs.service.fetch", bytes=nbytes):
+        d, m = jax.device_get(out)
+    stats["result_bytes"] += nbytes
+    return d, m
 
 
 def _pack_result(value: tuple[int, np.ndarray]) -> tuple:
@@ -304,7 +324,8 @@ class ServingService:
         # service-level counters (the scheduler's stats live on the
         # streaming layer); chunk_roundings counts admission-time widths
         # rounded up to the shard multiple (warned once, counted always)
-        self.stats = {"chunk_roundings": 0, "installs": 0}
+        self.stats = {"chunk_roundings": 0, "installs": 0,
+                      "result_bytes": 0}
         self._warned_rounding = False
 
         if (mesh is not None or devices is not None) and getattr(
@@ -396,8 +417,9 @@ class ServingService:
 
     def _chunks(self, plan: QueryPlan, chunk: int | None = None):
         """Yield ``(unique_rows (chunk,), live, dispatch)`` per lane chunk.
-        ``dispatch()`` enqueues the device program and returns un-synced
-        device arrays ``(dist (chunk,), edge_mask (chunk, E))``.
+        ``dispatch()`` copies the chunk's index arrays to the device,
+        enqueues the lane program and returns un-synced device arrays
+        ``(dist (chunk,), edge_mask (chunk, E))``.
 
         ``chunk`` overrides the service's width for this plan (the
         streaming admission layer picks it adaptively); every jitted lane
@@ -425,24 +447,22 @@ class ServingService:
         lid = idx._lid_np
 
         for sel, live in chunk_padded(plan.lanes[LANE_GENERAL], chunk):
-            yield sel, live, partial(self._general_step,
-                                     jnp.asarray(plan.cu[sel]),
-                                     jnp.asarray(plan.cv[sel]))
+            yield sel, live, partial(_launch, self._general_step,
+                                     plan.cu[sel], plan.cv[sel])
 
         for sel, live in chunk_padded(plan.lanes[LANE_LANDMARK_PAIR],
                                       chunk):
-            yield sel, live, partial(idx.landmark_pair_step,
-                                     jnp.asarray(lid[plan.cu[sel]]),
-                                     jnp.asarray(lid[plan.cv[sel]]))
+            yield sel, live, partial(_launch, idx.landmark_pair_step,
+                                     lid[plan.cu[sel]], lid[plan.cv[sel]])
 
         one = plan.lanes[LANE_ONE_SIDED]
         if one.size:
             roots, r_idx = onesided_roots(plan.cu[one], plan.cv[one],
                                           idx._is_landmark_np, lid)
             for pos, live in chunk_padded(np.arange(one.size), chunk):
-                yield one[pos], live, partial(idx.landmark_onesided_step,
-                                              jnp.asarray(roots[pos]),
-                                              jnp.asarray(r_idx[pos]))
+                yield one[pos], live, partial(_launch,
+                                              idx.landmark_onesided_step,
+                                              roots[pos], r_idx[pos])
 
     def _execute(self, plan: QueryPlan) -> Iterator[tuple]:
         """Drain all device lanes: yields host tuples ``(unique_rows,
@@ -460,7 +480,7 @@ class ServingService:
         def drain(limit: int):
             while len(inflight) > limit:
                 sel, live, out = inflight.popleft()
-                d, m = jax.device_get(out)
+                d, m = fetch_chunk(out, self.stats)
                 yield sel[:live], d[:live], m[:live]
 
         for sel, live, dispatch in self._chunks(plan):

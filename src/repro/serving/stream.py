@@ -68,10 +68,10 @@ from collections import deque
 from dataclasses import dataclass
 from typing import Iterable, Iterator, Sequence
 
-import jax
 import numpy as np
 
 from ..core.graph import INF
+from ..tracing import span
 from . import debug
 from .clock import ManualClock, SystemClock  # noqa: F401  (re-export)
 from .metrics import LatencyHistogram
@@ -83,7 +83,7 @@ from .planner import (
     d_top_of,
     plan_from_pairs,
 )
-from .service import ServingService, _NO_EDGES
+from .service import ServingService, _NO_EDGES, fetch_chunk
 
 
 @dataclass(frozen=True)
@@ -285,6 +285,7 @@ class StreamingService:
             "handed_off": 0,       # pending pairs exported to a peer
                                    # replica (handoff_pending)
             "updates": 0,          # epoch advances installed (§13)
+            "result_bytes": 0,     # chunk results copied device -> host
         }, what="StreamingService.stats")
         # waits are wall-clock (injected-clock) seconds from submit to
         # admission — the queueing latency the deadline bounds; bounded
@@ -891,33 +892,40 @@ class StreamingService:
     # -- resolution ----------------------------------------------------------
 
     def _sync_until(self, limit: int) -> None:  # qbslint: locked
-        now = self.clock.now()
         while len(self._inflight) > limit:
             plan, sel, live, ep, out = self._inflight.popleft()
-            d, m = jax.device_get(out)
-            for k in range(live):
-                row = int(sel[k])
-                key = (int(plan.cu[row]), int(plan.cv[row]))
-                eids = np.flatnonzero(m[k]).astype(np.int32)
-                eids.flags.writeable = False   # shared: waiters + cache
-                dist = int(d[k])
-                d_top = d_top_of(int(plan.lane[row]), dist, INF)
-                flight = self._flight[key]
-                for fut in flight.pop(ep):
-                    fut.epoch = ep
-                    fut._resolve(dist, eids, d_top)
-                    # resolution-time latency on the injected clock: under
-                    # ManualClock this is a pure function of the trace
-                    self.lat_hist[fut.qos].observe(
-                        (now - fut.t_submit) * 1e6)
-                if not flight:
-                    del self._flight[key]
-                if key not in self._waiting and key not in self._flight:
-                    # the pair may have been re-submitted (pending at a
-                    # newer epoch) or still be in flight under another
-                    # epoch — its deadline must survive this resolution
-                    self._deadline.pop(key, None)
-                self.service.cache_put((key[0], key[1], ep), (dist, eids))
+            d, m = fetch_chunk(out, self.stats)
+            # resolution time is read once the chunk is on the host, so
+            # the latency holds the wait for the device too
+            now = self.clock.now()
+            with span("qbs.stream.resolve"):
+                self._resolve_chunk(plan, sel, live, ep, d, m, now)
+
+    def _resolve_chunk(self, plan, sel, live, ep, d, m, now):  # qbslint: locked
+        """Resolve the futures of one fetched chunk at time ``now`` and
+        put its answers through the cache."""
+        for k in range(live):
+            row = int(sel[k])
+            key = (int(plan.cu[row]), int(plan.cv[row]))
+            eids = np.flatnonzero(m[k]).astype(np.int32)
+            eids.flags.writeable = False   # shared: waiters + cache
+            dist = int(d[k])
+            d_top = d_top_of(int(plan.lane[row]), dist, INF)
+            flight = self._flight[key]
+            for fut in flight.pop(ep):
+                fut.epoch = ep
+                fut._resolve(dist, eids, d_top)
+                # resolution-time latency on the injected clock: under
+                # ManualClock this is a pure function of the trace
+                self.lat_hist[fut.qos].observe((now - fut.t_submit) * 1e6)
+            if not flight:
+                del self._flight[key]
+            if key not in self._waiting and key not in self._flight:
+                # the pair may have been re-submitted (pending at a
+                # newer epoch) or still be in flight under another
+                # epoch — its deadline must survive this resolution
+                self._deadline.pop(key, None)
+            self.service.cache_put((key[0], key[1], ep), (dist, eids))
 
     def _lane_of(self, key: tuple[int, int]) -> int:
         """Scalar lane classification for submit-time (cache-hit)

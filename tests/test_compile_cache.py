@@ -52,3 +52,34 @@ def test_compile_cache_directory(tmp_path, where):
     else:
         got = _probe(None, compile_now=False)
         assert got["used"] == got["config"] == str(ROOT / ".jax_cache")
+
+
+_SCOPED = """
+import json, jax, jax.numpy as jnp
+from repro.launch.compile_cache import configure_compile_cache
+configure_compile_cache()
+
+@jax.jit
+def f(x):
+    with jax.named_scope({name!r}):
+        return jnp.sin(x) @ x.T
+
+print(json.dumps({{"hlo": f.lower(jnp.ones((8, 8))).compile().as_text()}}))
+"""
+
+
+def test_cached_program_keeps_its_own_scope_names(tmp_path):
+    """Two programs with the same ops under other scope names: the second
+    is not served the first's compile, so its trace names its own phases."""
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+    for name in ("qbs.first", "qbs.second"):
+        proc = subprocess.run([sys.executable, "-c", _SCOPED.format(name=name)],
+                              env=env, capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-4000:]
+        hlo = json.loads(proc.stdout.strip().splitlines()[-1])["hlo"]
+        assert f"/{name}/" in hlo
+    assert any(tmp_path.iterdir())
