@@ -9,10 +9,17 @@ vertices,
 
     next[k, w] = OR_{e : dst[e] = w}  values[k, src[e]] & mask[e]
 
+The edge list is symmetric (every edge in both orientations, and any baked
+mask with it), so the same OR runs over each vertex's own CSR row:
+
+    next[k, w] = OR_{e : src[e] = w}  values[k, dst[e]] & mask[e]
+
 This module owns that operation behind pluggable backends:
 
-* ``segment``  — the edge-list ``jax.ops.segment_max`` push relay (the seed
-                 formulation; default, bit-identical reference).
+* ``segment``  — the default: a *row pull* over the src-sorted edge list
+                 (``graph.from_edges`` sorts it and keeps ``indptr``): gather
+                 ``values[:, dst]`` in CSR order and OR each contiguous row
+                 with a blocked prefix count (``_row_or``), no scatter op.
 * ``csr``      — pull formulation over the CSR (src-sorted) edge layout:
                  ``next[w] = OR_{e in row w} values[dst[e]]``, valid because
                  the graph and any baked edge mask are symmetric.  The
@@ -76,6 +83,37 @@ def segment_or(
         messages.astype(acc_dtype).T, segment_ids, num_segments=num_segments
     )
     return (acc > 0).T
+
+
+# Edges per block of the row reduction: in-block prefix counts stay below
+# 256, and bf16 holds every integer up to 256 exactly, so the triangular
+# matmul below is exact in and out.
+ROW_BLOCK = 256
+
+
+def _row_or(messages: jax.Array, indptr: jax.Array) -> jax.Array:
+    """OR ``(K, E)`` boolean messages over the contiguous rows
+    ``[indptr[w], indptr[w + 1])``: ``(K, V)``, no scatter.
+
+    A row holds a message iff the count of messages before its end exceeds
+    the count before its start.  The counts come blocked: an exclusive
+    prefix inside each block of ``ROW_BLOCK`` edges is one triangular 0/1
+    matmul on the MXU, and the blocks' offsets one short cumulative sum
+    over ``E / ROW_BLOCK`` totals; only the ``V + 1`` row boundaries are
+    read back."""
+    k, e = messages.shape
+    nb = e // ROW_BLOCK + 1              # one slot past E, so indptr[V] fits
+    m = jnp.pad(messages, ((0, 0), (0, nb * ROW_BLOCK - e)))
+    m = m.astype(jnp.bfloat16).reshape(k, nb, ROW_BLOCK)
+    i = jnp.arange(ROW_BLOCK)
+    before = (i[:, None] < i[None, :]).astype(jnp.bfloat16)
+    excl = jnp.einsum("knb,bc->knc", m, before,
+                      preferred_element_type=jnp.bfloat16)
+    tot = excl[..., -1].astype(jnp.int32) + m[..., -1].astype(jnp.int32)
+    offs = jnp.cumsum(tot, axis=1) - tot
+    at = (offs[:, indptr // ROW_BLOCK]
+          + excl.reshape(k, -1)[:, indptr].astype(jnp.int32))
+    return at[:, 1:] != at[:, :-1]
 
 
 def _dense_or_matmul(frontier: jax.Array, adjacency: jax.Array) -> jax.Array:
@@ -144,26 +182,33 @@ class FrontierEngine:
             out = self._relay_hybrid(f)
         return out[0] if squeeze else out
 
-    def scatter(self, messages: jax.Array) -> jax.Array:
-        """Generic per-edge OR-scatter ``(K, E) -> (K, V)`` keyed by ``dst``
-        (edge ids index the *original* edge list).  Messages that cannot be
-        factored into per-vertex values (the recover chain's label-decrement
-        coupling) relay through here; it is ``segment``-based on every
-        backend because a dense block cannot represent arbitrary per-edge
-        messages."""
-        squeeze = messages.ndim == 1
-        m = messages[None] if squeeze else messages
-        out = segment_or(m, self.arrays["dst"], self.n_vertices)
+    def pull(self, values: jax.Array,
+             edge_mask: jax.Array | None = None) -> jax.Array:
+        """Row pull ``(K, V) -> (K, V)`` (or ``(V,) -> (V,)``) with a
+        per-edge condition: next[k, w] = OR over edges e of row w
+        (``src[e] == w``) of ``values[k, dst[e]] & mask[e] &
+        edge_mask[k, e]``, where ``mask`` is the build-time edge mask and
+        ``edge_mask`` is ``(E,)`` or ``(K, E)`` over the original edge list.
+        Messages that do not factor into per-vertex values (the recover
+        chain's label-decrement coupling) relay through here; it reads the
+        edge list on every backend, because a dense block cannot represent
+        arbitrary per-edge conditions."""
+        squeeze = values.ndim == 1
+        f = values[None] if squeeze else values
+        msgs = f[:, self.arrays["dst"]]
+        mask = self.arrays.get("mask")
+        if mask is not None:
+            msgs = msgs & mask
+        if edge_mask is not None:
+            msgs = msgs & edge_mask
+        out = _row_or(msgs, self.arrays["indptr"])
         return out[0] if squeeze else out
 
     # -- backends ------------------------------------------------------------
 
     def _relay_segment(self, f: jax.Array) -> jax.Array:
-        msgs = f[:, self.arrays["src"]]
-        mask = self.arrays.get("mask")
-        if mask is not None:
-            msgs = msgs & mask
-        return segment_or(msgs, self.arrays["dst"], self.n_vertices)
+        # symmetric edge list and mask: the in-edges of w are its own row
+        return self.pull(f)
 
     def _relay_csr(self, f: jax.Array) -> jax.Array:
         # Pull over the src-sorted (CSR-row) layout: by edge-set and mask
@@ -332,22 +377,28 @@ def make_relay(
 
     ``edge_mask`` is a *static* per-edge boolean (the G- mask); it must be
     symmetric (``mask[e] == mask[rev(e)]``), which holds for any mask of the
-    form ``f[src] & f[dst]`` on the symmetrized edge list.  ``csr`` and
-    ``hybrid`` additionally require the edge set itself to be symmetric,
-    which ``graph.from_edges`` guarantees.  Build is host-side (numpy).
+    form ``f[src] & f[dst]`` on the symmetrized edge list.  The relays
+    pull over CSR rows, so the edge set itself must be symmetric and
+    sorted by ``src`` with ``graph.indptr`` its row bounds, as
+    ``graph.from_edges`` leaves it; an edge list out of ``src`` order is
+    refused.  Build is host-side (numpy).
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; expected {BACKENDS}")
     v, e = graph.n_vertices, graph.n_edges
     src_np = np.asarray(graph.src)
     dst_np = np.asarray(graph.dst)
+    if np.any(src_np[:-1] > src_np[1:]):
+        raise ValueError("edge list is not sorted by src; build the graph "
+                         "with graph.from_edges")
     mask_np = None if edge_mask is None else np.asarray(edge_mask).astype(bool)
 
-    arrays: dict[str, Any] = {"src": graph.src, "dst": graph.dst}
+    arrays: dict[str, Any] = {"src": graph.src, "dst": graph.dst,
+                              "indptr": graph.indptr}
+    if mask_np is not None:
+        arrays["mask"] = jnp.asarray(mask_np)
 
     if backend == "segment":
-        if mask_np is not None:
-            arrays["mask"] = jnp.asarray(mask_np)
         return FrontierEngine(arrays, backend=backend, n_vertices=v, n_edges=e)
 
     if backend == "csr":
@@ -397,6 +448,7 @@ def abstract_engine(n_vertices: int, n_edges: int, *,
     arrays: dict[str, Any] = {
         "src": jax.ShapeDtypeStruct((n_edges,), i32),
         "dst": jax.ShapeDtypeStruct((n_edges,), i32),
+        "indptr": jax.ShapeDtypeStruct((n_vertices + 1,), i32),
     }
     if masked:
         arrays["mask"] = jax.ShapeDtypeStruct((n_edges,), jnp.bool_)

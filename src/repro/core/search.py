@@ -6,9 +6,9 @@ landmarks from the labelling).
 TPU adaptation notes (see DESIGN.md §2):
 
 * Queues -> level-synchronous frontier masks; every step is an edge-parallel
-  relay through the pluggable ``core.frontier`` engine (``segment_max`` by
-  default, CSR-blocked or hybrid hub/tail via ``backend=``), so hub
-  vertices never serialize a lane.
+  relay through the pluggable ``core.frontier`` engine (a pull over the
+  sorted CSR rows by default, CSR-blocked or hybrid hub/tail via
+  ``backend=``), so hub vertices never serialize a lane.
 * The paper's recover search walks pointers from anchor set Z.  Here the
   labels act as *global* distance certificates, which turns most of the walk
   into a single pointwise test:  a vertex x lies on a landmark-free shortest
@@ -245,25 +245,29 @@ def _label_col(ctx: SearchContext, k):
         jax.lax.dynamic_index_in_dim(ctx.label_dist, k, axis=1, keepdims=False))
 
 
-def _side_attach(ctx: SearchContext, depth, sigma, ld, dec, k, max_chain: int):
+def _side_attach(ctx: SearchContext, depth, sigma, ld, dec, inc, k,
+                 max_chain: int):
     """Component (i)/(ii) for landmark ``k``: edges of landmark-free shortest
     t->r_k paths for the sketch edge (r_k, t) of weight ``sigma``.  ``ld``
-    is the (V,) label column of r_k and ``dec`` the (E,) G- edges along
-    which it decrements (``ld[dst] == ld[src] - 1``)."""
+    is the (V,) label column of r_k, ``dec`` the (E,) G- edges along which
+    it decrements (``ld[dst] == ld[src] - 1``) and ``inc`` their reverses
+    (``ld[src] == ld[dst] - 1``)."""
     # Pointwise certificate: G- BFS prefix + label suffix == sigma.
     on = (ld < INF) & (depth < INF) & (sigma < INF) & (depth + ld == sigma)
 
     # Anchor-chain closure for path segments beyond the explored ball
     # (paper's Z-walk): extend along label-decrement edges in G-.  The
-    # label-decrement coupling ties src and dst, so this is a generic
-    # per-edge message, not a vertex-value relay.
+    # label-decrement coupling ties src and dst, so this is a per-edge
+    # condition, not a vertex-value relay.  w joins when a decrement edge
+    # (x, w) leaves an on-path x; by symmetry that edge's reverse (w, x)
+    # lies in w's own row and satisfies ``inc``, so the step is a row pull.
     def cond(c):
         _, changed, it = c
         return changed & (it < max_chain)
 
     def body(c):
         on, _, it = c
-        new_on = on | ctx.engine.scatter(dec & on[ctx.src])
+        new_on = on | ctx.engine.pull(on, inc)
         return new_on, jnp.any(new_on & ~on), it + 1
 
     t = jnp.any(on)
@@ -307,10 +311,11 @@ def recover_search(ctx: SearchContext, q: Query, depth_u, depth_v,
         ld = _label_col(ctx, k)
         ls, ldd = ld[ctx.src], ld[ctx.dst]
         dec = ctx.gminus_e & (ldd < INF) & (ldd == ls - 1)
-        edges = edges | _side_attach(ctx, depth_u, q.du_land[k], ld, dec, k,
-                                     max_chain)
-        edges = edges | _side_attach(ctx, depth_v, q.dv_land[k], ld, dec, k,
-                                     max_chain)
+        inc = ctx.gminus_e & (ls < INF) & (ls == ldd - 1)
+        edges = edges | _side_attach(ctx, depth_u, q.du_land[k], ld, dec, inc,
+                                     k, max_chain)
+        edges = edges | _side_attach(ctx, depth_v, q.dv_land[k], ld, dec, inc,
+                                     k, max_chain)
 
         # (iii) interior, landmark i = k
         def t_step(j, t):

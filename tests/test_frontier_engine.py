@@ -23,7 +23,8 @@ from repro.core import (
     select_landmarks,
 )
 from repro.core.baselines import bfs_spg, bibfs_spg
-from repro.core.frontier import segment_or
+from repro.core.frontier import bfs_depths, segment_or
+from repro.core.graph import INF, from_edges
 
 BACKENDS = ("segment", "csr", "hybrid")
 
@@ -75,13 +76,145 @@ def test_masked_relay_identical_across_backends(gen):
 
 
 def test_scatter_matches_segment_or():
+    """Per-edge conditions pull into their rows on every backend: the
+    same booleans as a ``src``-keyed ``segment_or`` of the messages."""
     g = GRAPHS["gnp"]()
     rng = np.random.default_rng(3)
-    msgs = jnp.asarray(rng.random((4, g.n_edges)) < 0.2)
-    want = np.asarray(segment_or(msgs, g.dst, g.n_vertices))
+    vals = jnp.asarray(rng.random((4, g.n_vertices)) < 0.4)
+    emask = jnp.asarray(rng.random((4, g.n_edges)) < 0.5)
+    want = np.asarray(segment_or(vals[:, g.dst] & emask, g.src, g.n_vertices))
     for name, eng in _engines(g).items():
-        got = np.asarray(eng.scatter(msgs))
+        got = np.asarray(eng.pull(vals, emask))
         assert (got == want).all(), name
+
+
+def _push(g, vals, emask=None):
+    """The seed's relay: messages keyed by ``dst``, reduced by scatter."""
+    msgs = vals[..., g.src]
+    if emask is not None:
+        msgs = msgs & emask
+    return np.asarray(segment_or(jnp.atleast_2d(msgs), g.dst, g.n_vertices))
+
+
+def _star_plus(n, hub_deg, seed):
+    """A hub whose row spans several reduction blocks, plus sparse edges."""
+    rng = np.random.default_rng(seed)
+    star = np.stack([np.zeros(hub_deg, np.int64), np.arange(1, hub_deg + 1)], 1)
+    rest = rng.integers(0, n, (n, 2))
+    return from_edges(np.concatenate([star, rest]), n)
+
+
+def _case_rows_relay(name):
+    g = {
+        "gnp": lambda: GRAPHS["gnp"](),
+        "ring_of_cliques": lambda: GRAPHS["ring_of_cliques"](),
+        # isolated vertices (empty rows) and self-loop pad slots; 512 slots
+        # is a whole number of reduction blocks
+        "padded": lambda: gnp_random_graph(50, 2.0, seed=4, pad_vertices_to=64,
+                                           pad_edges_to=512),
+        "hub_rows": lambda: _star_plus(700, 600, seed=9),
+    }[name]()
+    rng = np.random.default_rng(21)
+    eng = make_relay(g)
+    vals = jnp.asarray(rng.random((5, g.n_vertices)) < 0.2)
+    assert (np.asarray(eng.relay(vals)) == _push(g, vals)).all()
+    # (V,) in, (V,) out
+    assert (np.asarray(eng.relay(vals[2])) == _push(g, vals[2])[0]).all()
+
+
+def _case_gminus(_):
+    g = GRAPHS["barabasi_albert"]()
+    idx = QbSIndex.build(g, n_landmarks=5)
+    eng = idx.ctx.engine
+    rng = np.random.default_rng(5)
+    vals = jnp.asarray(rng.random((3, g.n_vertices)) < 0.3)
+    want = _push(g, vals, idx.ctx.gminus_e)
+    assert (np.asarray(eng.relay(vals)) == want).all()
+
+
+def _case_edge_mask(_):
+    """A per-query (K, E) condition and an (E,) one, on a masked engine."""
+    g = GRAPHS["random_regular"]()
+    rng = np.random.default_rng(8)
+    vkeep = rng.random(g.n_vertices) < 0.8
+    gm = vkeep[np.asarray(g.src)] & vkeep[np.asarray(g.dst)]
+    eng = make_relay(g, edge_mask=gm)
+    vals = jnp.asarray(rng.random((4, g.n_vertices)) < 0.4)
+    for em in (rng.random((4, g.n_edges)) < 0.5, rng.random(g.n_edges) < 0.5):
+        em = jnp.asarray(em)
+        want = np.asarray(segment_or(vals[:, g.dst] & gm & em, g.src,
+                                     g.n_vertices))
+        assert (np.asarray(eng.pull(vals, em)) == want).all()
+
+
+def _case_unsorted(_):
+    """The relays pull over CSR rows: an edge list out of ``src`` order is
+    refused on every backend."""
+    g = GRAPHS["gnp"]()
+    perm = np.random.default_rng(2).permutation(g.n_edges)
+    shuffled = type(g)(g.indptr, g.src[perm], g.dst[perm])
+    for backend in BACKENDS:
+        with pytest.raises(ValueError, match="not sorted by src"):
+            make_relay(shuffled, backend=backend)
+
+
+def _case_recover_chain(_):
+    """Recover's pulled anchor chain against the seed's dst-keyed scatter
+    form, on balls much smaller than the paths they attach."""
+    from repro.core.search import _label_col, _side_attach
+
+    g = grid_graph(3, 14)   # two landmarks, long corridors between them
+    idx = QbSIndex.build(g, n_landmarks=2)
+    ctx = idx.ctx
+    src, dst = np.asarray(g.src), np.asarray(g.dst)
+    full = make_relay(g)
+    grew = False
+    for k in range(idx.scheme.n_landmarks):
+        ld = _label_col(ctx, k)
+        ls, ldd = ld[ctx.src], ld[ctx.dst]
+        dec = ctx.gminus_e & (ldd < INF) & (ldd == ls - 1)
+        inc = ctx.gminus_e & (ls < INF) & (ls == ldd - 1)
+        ld_np = np.asarray(ld)
+        for t in np.flatnonzero(ld_np < INF)[::3]:
+            depth = bfs_depths(full, jnp.int32(t), 64, bound=jnp.int32(1))
+            sigma = jnp.int32(ld_np[t])
+            got = np.asarray(_side_attach(ctx, depth, sigma, ld, dec, inc,
+                                          jnp.int32(k), 64))
+            # the seed's chain: scatter dec & on[src] by dst to a fixpoint
+            on = np.asarray((ld < INF) & (depth < INF) & (depth + ld == sigma))
+            start = on.sum()
+            while True:
+                new = on | np.asarray(segment_or(
+                    jnp.asarray(np.asarray(dec) & on[src])[None],
+                    g.dst, g.n_vertices))[0]
+                if (new == on).all():
+                    break
+                on = new
+            grew |= on.sum() > start + 2
+            lid, dec_np = np.asarray(ctx.lid), np.asarray(dec)
+            want = ((dec_np & on[src] & on[dst])
+                    | ((lid[dst] == k) & on[src] & (ld_np[src] == 1))
+                    | ((lid[src] == k) & on[dst] & (ld_np[dst] == 1)))
+            assert (got == want).all(), (k, t)
+    assert grew, "no chain ran past its ball"
+
+
+ROW_CASES = {
+    "rows_gnp": _case_rows_relay,
+    "rows_ring_of_cliques": _case_rows_relay,
+    "rows_padded": _case_rows_relay,
+    "rows_hub_rows": _case_rows_relay,
+    "gminus_mask": _case_gminus,
+    "per_query_edge_mask": _case_edge_mask,
+    "unsorted_list_refused": _case_unsorted,
+    "recover_chain": _case_recover_chain,
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_CASES))
+def test_row_pull_bit_identical(case):
+    """The row-pull reduction gives the seed scatter relay's booleans."""
+    ROW_CASES[case](case.removeprefix("rows_"))
 
 
 def test_hybrid_pallas_kernel_path():
