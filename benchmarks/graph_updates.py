@@ -14,11 +14,11 @@ update-median; the bench gate holds it above an absolute floor
 (``--update-speedup-floor``, default 5) rather than a relative threshold
 — the ratio normalizes machine speed out, like ``roofline_frac``.
 
-Warmup discipline matters here: the first update doubles the CSR edge
-capacity (build packs slots exactly), and the incremental path pads its
-affected-landmark recomputes to the ``pad_width`` shape ladder — so the
-bench stabilizes capacity first, then warms every ladder width the churn
-threshold admits, and only then starts the clock.
+Warmup discipline matters here: the first update takes the CSR's first
+edge-capacity step (build packs slots exactly), and the update programs
+compile once per capacity — so the bench takes that step first, warms
+the update path there (``QbSIndex.warm_update_path``), and only then
+starts the clock.
 """
 from __future__ import annotations
 
@@ -30,7 +30,6 @@ import numpy as np
 
 from repro.core import QbSIndex, barabasi_albert_graph
 from repro.core.graph import edge_set
-from repro.core.packing import pad_width
 
 REPO = Path(__file__).resolve().parents[1]
 BENCH_PATH = REPO / "BENCH.json"
@@ -45,34 +44,6 @@ def _block(index: QbSIndex) -> None:
     import jax
 
     jax.block_until_ready(index.packed.label_dist)
-
-
-def _warm_ladder(index: QbSIndex) -> None:
-    """Compile every shape the incremental path can hit: the affected-root
-    BFS, the packed patch scatter and the label-table scatter, at each
-    ``pad_width`` ladder width the churn threshold admits."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.frontier import make_relay
-    from repro.core.labelling import _build_labelling_rows
-    from repro.core.packing import patch_packed
-
-    scheme = index.scheme
-    lms = np.asarray(scheme.landmarks)
-    engine = make_relay(index.graph, backend="segment")
-    widths = sorted({pad_width(k)
-                     for k in range(1, int(CHURN * len(lms)) + 1)})
-    for w in widths:
-        roots = jnp.asarray(lms[:w], jnp.int32)
-        jax.block_until_ready(_build_labelling_rows(
-            engine, roots, scheme.landmarks, scheme.is_landmark, 256))
-        jax.block_until_ready(patch_packed(
-            index.packed, scheme, index._lm_dist_host,
-            np.arange(w, dtype=np.int32)).label_dist)
-        idx_w = jnp.arange(w, dtype=jnp.int32)
-        jax.block_until_ready(scheme.label_dist.at[:, idx_w].set(
-            scheme.label_dist[:, idx_w]))
 
 
 def run(scale: float = 1.0, **_) -> list[tuple]:
@@ -93,11 +64,11 @@ def run(scale: float = 1.0, **_) -> list[tuple]:
         es = edge_set(cur.graph)
         return tuple(int(x) for x in es[rng.integers(0, len(es))])
 
-    # capacity-stabilizing update (edge slots double once, then hold),
-    # then warm every incremental shape and both terminal branches
+    # capacity-stabilizing update (the edge slots take one step, then
+    # hold), then warm the update programs and both terminal branches
     cur = index.apply_update(inserts=[rand_absent(index)],
                              churn_threshold=CHURN)
-    _warm_ladder(cur)
+    cur.warm_update_path(CHURN)
     _block(cur.apply_update(inserts=[rand_absent(cur)], churn_threshold=0.0))
     # Small query legs: random pairs at this V are SPG-expensive (the
     # (N, E) edge-mask recover path), so the trace interleaves a few

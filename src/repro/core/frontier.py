@@ -372,6 +372,7 @@ def make_relay(
     n_hubs: int | None = None,
     block_size: int = 0,
     use_pallas: bool | None = None,
+    host: tuple[np.ndarray, np.ndarray, np.ndarray] | None = None,
 ) -> FrontierEngine:
     """Build a ``FrontierEngine`` for ``graph``.
 
@@ -381,25 +382,27 @@ def make_relay(
     pull over CSR rows, so the edge set itself must be symmetric and
     sorted by ``src`` with ``graph.indptr`` its row bounds, as
     ``graph.from_edges`` leaves it; an edge list out of ``src`` order is
-    refused.  Build is host-side (numpy).
+    refused.  Build is host-side (numpy); ``host`` is ``graph``'s
+    ``(indptr, src, dst)`` on the host where the caller holds them (the
+    update path's mirror), so they are not copied back from the device.
     """
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; expected {BACKENDS}")
     v, e = graph.n_vertices, graph.n_edges
-    src_np = np.asarray(graph.src)
-    dst_np = np.asarray(graph.dst)
+    src_np, dst_np = host[1:] if host is not None else (
+        np.asarray(graph.src), np.asarray(graph.dst))
     if np.any(src_np[:-1] > src_np[1:]):
         raise ValueError("edge list is not sorted by src; build the graph "
                          "with graph.from_edges")
-    mask_np = None if edge_mask is None else np.asarray(edge_mask).astype(bool)
 
     arrays: dict[str, Any] = {"src": graph.src, "dst": graph.dst,
                               "indptr": graph.indptr}
-    if mask_np is not None:
-        arrays["mask"] = jnp.asarray(mask_np)
+    if edge_mask is not None:
+        arrays["mask"] = jnp.asarray(edge_mask, bool)
 
     if backend == "segment":
         return FrontierEngine(arrays, backend=backend, n_vertices=v, n_edges=e)
+    mask_np = None if edge_mask is None else np.asarray(edge_mask).astype(bool)
 
     if backend == "csr":
         gather = dst_np

@@ -3,12 +3,15 @@
 The QbS engine operates on unweighted, undirected graphs stored as a
 symmetrized directed edge list (every undirected edge appears in both
 orientations) plus a CSR ``indptr``.  All shapes are static so every phase
-jits cleanly; padding uses self-loops on an isolated padding vertex, which
-are no-ops for level-synchronous BFS (a self loop re-delivers a message to
-an already-visited vertex).
+jits cleanly; padding uses self-loops on the last vertex (an isolated
+padding vertex where ``pad_vertices_to`` asks for one), which are no-ops
+for level-synchronous BFS (a self loop re-delivers a message to an
+already-visited vertex) and lie on no shortest path.  ``EdgeSlots`` is the
+host mirror the update path merges edge batches into.
 """
 from __future__ import annotations
 
+from functools import cached_property
 from typing import NamedTuple
 
 import jax
@@ -144,35 +147,155 @@ def edge_keys(edges: np.ndarray, n_vertices: int) -> np.ndarray:
     return np.unique(lo * np.int64(n_vertices) + hi)
 
 
+def capacity_step(cap: int) -> int:
+    """The edge-slot capacity one step above ``cap``: ``cap // 16`` more
+    slots (at least one undirected edge's two), so a capacity never grows
+    by more than 1/16 at a time once it holds 32 slots."""
+    return cap + max(2, cap // 16)
+
+
+class EdgeSlots:
+    """Host mirror of a ``Graph``'s live edge slots, in CSR order.
+
+    Slot ``(s, d)`` has the key ``s * V + (d - s - 1) % V``: by source,
+    and under one source the neighbours above it, then those below it,
+    each ascending -- the order ``from_edges`` gives.  So one sorted int64
+    array is the whole CSR, and an update batch merges into it with
+    ``searchsorted`` and ``insert``/``delete`` instead of a sort.  ``rev``
+    is each live slot's reverse slot; ``capacity`` counts the padding
+    slots too (self loops on vertex ``V - 1``, after every live slot)."""
+
+    def __init__(self, n_vertices: int, keys: np.ndarray, rev: np.ndarray,
+                 capacity: int):
+        self.n_vertices = n_vertices
+        self.keys = keys          # (L,) int64, strictly ascending
+        self.rev = rev            # (L,) int64
+        self.capacity = capacity
+
+    @classmethod
+    def of_graph(cls, graph: Graph) -> "EdgeSlots":
+        """The mirror of a device ``Graph`` (one device-to-host copy)."""
+        s = np.asarray(graph.src, np.int64)
+        d = np.asarray(graph.dst, np.int64)
+        live = s != d                  # padding slots are the only self loops
+        keys = _slot_keys(s[live], d[live], graph.n_vertices)
+        if np.any(keys[1:] <= keys[:-1]):
+            raise ValueError("edge list is not in from_edges order")
+        rev = np.searchsorted(keys, _slot_keys(d[live], s[live],
+                                               graph.n_vertices))
+        return cls(graph.n_vertices, keys, rev, graph.n_edges)
+
+    @property
+    def n_live(self) -> int:
+        return int(self.keys.size)
+
+    @cached_property
+    def csr(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """``(indptr, src, dst)`` int32 on the host, padding included: the
+        arrays of ``graph()``."""
+        n = np.int64(self.n_vertices)
+        s = self.keys // n
+        d = (self.keys % n + s + 1) % n
+        pad = np.full(self.capacity - self.n_live, n - 1, np.int64)
+        src = np.concatenate([s, pad]).astype(np.int32)
+        dst = np.concatenate([d, pad]).astype(np.int32)
+        indptr = np.zeros(self.n_vertices + 1, np.int64)
+        indptr[1:] = np.cumsum(np.bincount(src, minlength=self.n_vertices))
+        return indptr.astype(np.int32), src, dst
+
+    def rev_slots(self) -> np.ndarray:
+        """``(capacity,)`` int32 reverse slot of every slot; every padding
+        slot maps to the first padding slot."""
+        pad = np.full(self.capacity - self.n_live, self.n_live, np.int64)
+        return np.concatenate([self.rev, pad]).astype(np.int32)
+
+    def graph(self) -> Graph:
+        """The device ``Graph``: bit-identical to ``from_edges`` over the
+        same edges at the same capacity, uploaded once."""
+        return Graph(*(jnp.asarray(a) for a in self.csr))
+
+    def has(self, keys: np.ndarray) -> np.ndarray:
+        """Which canonical edge keys (``lo * V + hi``) are present."""
+        n = self.n_vertices
+        want = _slot_keys(keys // n, keys % n, n)
+        at = np.minimum(np.searchsorted(self.keys, want), max(self.n_live - 1, 0))
+        return (self.keys[at] == want) if self.n_live else np.zeros(keys.size, bool)
+
+    def updated(self, inserts: np.ndarray, deletes: np.ndarray) -> "EdgeSlots":
+        """The mirror after an effective batch: ``inserts`` are canonical
+        keys of absent edges, ``deletes`` of present ones, both sorted and
+        disjoint.  O(L + k log L) for a batch of k edges.
+
+        The capacity keeps its step while the live slots fit.  A batch
+        that changes a full graph (no free slot, as ``from_edges`` builds
+        one) takes one step, and a batch that overflows takes as many as
+        it needs -- so a graph that takes no update keeps its exact size,
+        and every later batch within a step keeps every shape."""
+        n = self.n_vertices
+        lo_i, hi_i = inserts // n, inserts % n
+        lo_d, hi_d = deletes // n, deletes % n
+        gone = np.searchsorted(self.keys, np.concatenate([
+            _slot_keys(lo_d, hi_d, n), _slot_keys(hi_d, lo_d, n)]))
+        keep = np.ones(self.n_live, bool)
+        keep[gone] = False
+        old = np.flatnonzero(keep)
+        kept = self.keys[keep]
+        k = inserts.size
+        new = np.concatenate([_slot_keys(lo_i, hi_i, n), _slot_keys(hi_i, lo_i, n)])
+        order = np.argsort(new)
+        new = new[order]
+        at = np.searchsorted(kept, new)
+        keys = np.insert(kept, at, new)
+        # where each kept slot and each new slot lands
+        moved = np.full(self.n_live, -1, np.int64)
+        moved[old] = np.arange(kept.size) + np.searchsorted(
+            at, np.arange(kept.size), side="right")
+        placed = np.empty(2 * k, np.int64)
+        placed[order] = at + np.arange(2 * k)
+        rev = np.empty(keys.size, np.int64)
+        rev[moved[old]] = moved[self.rev[old]]
+        rev[placed] = np.concatenate([placed[k:], placed[:k]])
+        cap = self.capacity
+        if (k or deletes.size) and cap <= self.n_live:
+            cap = capacity_step(cap)
+        while cap < keys.size:
+            cap = capacity_step(cap)
+        return EdgeSlots(n, keys, rev, cap)
+
+
+def _slot_keys(s, d, n: int) -> np.ndarray:
+    """CSR-order keys of directed slots ``(s, d)`` (see ``EdgeSlots``)."""
+    s = np.asarray(s, np.int64)
+    d = np.asarray(d, np.int64)
+    return s * np.int64(n) + (d - s - 1) % np.int64(n)
+
+
 def apply_edge_updates(
-    graph: Graph,
+    slots: EdgeSlots,
     inserts: np.ndarray | None = None,
     deletes: np.ndarray | None = None,
-) -> Graph:
-    """Rebuild a ``Graph`` with ``inserts`` added and ``deletes`` removed
-    (an edge in both is inserted — inserts win).
+) -> tuple[EdgeSlots, np.ndarray, np.ndarray]:
+    """Apply one batch to a graph's mirror: ``(slots_new, ins, dels)``,
+    where ``ins``/``dels`` are the batch's *effective* canonical keys
+    (insert-of-absent, delete-of-present; an edge in both is inserted --
+    inserts win; self loops dropped).
 
-    Capacity-preserving: the new CSR keeps the old vertex count and edge-slot
-    capacity (doubling the slot capacity only when the new edge set
-    overflows it), so jitted consumers with static shapes keep their
-    compilation cache across epochs.  Because ``from_edges`` canonicalizes
-    deterministically, the resulting edge-slot ids for the surviving edges
-    depend only on the edge set — an independently rebuilt graph over the
-    same edges is bit-identical.
+    ``slots_new.graph()`` is the next epoch's ``Graph``.  The capacity
+    moves along ``capacity_step`` (see ``EdgeSlots.updated``), so jitted
+    consumers with static shapes keep their compilation cache across the
+    epochs of a step.  Because the layout is ``from_edges``' own, the
+    edge-slot ids of the surviving edges depend only on the edge set and
+    the capacity -- an independently rebuilt graph over the same edges at
+    the same capacity is bit-identical.
     """
-    n_v = graph.n_vertices
-    cur = edge_set(graph)
-    keys = cur[:, 0] * np.int64(n_v) + cur[:, 1]
-    if deletes is not None:
-        dk = edge_keys(deletes, n_v)
-        keys = keys[~np.isin(keys, dk)]
-    if inserts is not None:
-        keys = np.union1d(keys, edge_keys(inserts, n_v))
-    new_edges = np.stack([keys // n_v, keys % n_v], axis=1)
-    cap = graph.n_edges
-    while cap < 2 * len(keys):
-        cap = max(2 * cap, 2)
-    return from_edges(new_edges, n_v, pad_vertices_to=n_v, pad_edges_to=cap)
+    n_v = slots.n_vertices
+    none = np.zeros((0,), np.int64)
+    ins0 = none if inserts is None else edge_keys(inserts, n_v)
+    del0 = none if deletes is None else edge_keys(deletes, n_v)
+    ins = ins0[~slots.has(ins0)]                  # insert-of-absent only
+    dels = del0[slots.has(del0)]                  # delete-of-present only
+    dels = dels[~np.isin(dels, ins0)]             # inserts win a tie
+    return slots.updated(ins, dels), ins, dels
 
 
 def to_networkx(graph: Graph):

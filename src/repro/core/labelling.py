@@ -29,7 +29,7 @@ import numpy as np
 
 from .frontier import FrontierEngine, make_relay
 from .graph import INF, Graph
-from .packing import pad_width
+from ..tracing import scope
 
 
 class LabellingScheme(NamedTuple):
@@ -68,7 +68,7 @@ def _bfs_rows(
 
     Each row is independent of the others (the frontier is per-row), so a
     subset of roots computes bit-identical rows to the full-R build — the
-    property the incremental update path (``update_labelling``) relies on.
+    property the incremental update path (``update_tables``) relies on.
     """
     K = roots.shape[0]
     V = engine.n_vertices
@@ -103,13 +103,7 @@ def _bfs_rows(
     return depth, reach_l
 
 
-@partial(jax.jit, static_argnames=("max_levels",))
-def _build_labelling_arrays(
-    engine: FrontierEngine,
-    landmarks: jax.Array,
-    is_landmark: jax.Array,
-    max_levels: int,
-):
+def _labelling_arrays(engine, landmarks, is_landmark, max_levels):
     R = landmarks.shape[0]
     depth, reach_l = _bfs_rows(engine, landmarks, is_landmark, max_levels)
 
@@ -127,29 +121,12 @@ def _build_labelling_arrays(
     meta_w = jnp.minimum(meta_w, meta_w.T)
 
     meta_dist = meta_apsp(meta_w)
-    return label_dist, meta_w, meta_dist
+    return label_dist, meta_w, meta_dist, depth
 
 
 @partial(jax.jit, static_argnames=("max_levels",))
-def _build_labelling_rows(
-    engine: FrontierEngine,
-    roots: jax.Array,
-    landmarks: jax.Array,
-    is_landmark: jax.Array,
-    max_levels: int,
-):
-    """The incremental-update slice of the build: BFS rows for a (padded)
-    subset of landmark roots on the post-update graph, returning exactly the
-    pieces ``update_labelling`` scatters back into the old scheme — depth
-    rows ``(K, V)`` (the new lm_dist rows), label columns ``(V, K)`` and
-    raw (pre-symmetrization) meta rows ``(K, R)``."""
-    depth, reach_l = _bfs_rows(engine, roots, is_landmark, max_levels)
-    valid = reach_l & (~is_landmark)[None, :]
-    label_cols = jnp.where(valid, depth, INF).T.astype(jnp.int32)   # (V, K)
-    at_land = depth[:, landmarks]
-    l_at_land = reach_l[:, landmarks]
-    meta_rows = jnp.where(l_at_land, at_land, INF).astype(jnp.int32)  # (K, R)
-    return depth, label_cols, meta_rows
+def _build_labelling_arrays(engine, landmarks, is_landmark, max_levels: int):
+    return _labelling_arrays(engine, landmarks, is_landmark, max_levels)[:3]
 
 
 def meta_apsp(meta_w: jax.Array) -> jax.Array:
@@ -167,11 +144,6 @@ def meta_apsp(meta_w: jax.Array) -> jax.Array:
     return jnp.minimum(d, INF)
 
 
-# Standalone jitted entry for host callers (update_labelling); the build
-# path traces meta_apsp inside its own jitted program.
-_meta_apsp_j = jax.jit(meta_apsp)
-
-
 def build_labelling(
     graph: Graph, landmarks: np.ndarray, *, max_levels: int = 256,
     backend: str = "segment", engine: FrontierEngine | None = None,
@@ -185,8 +157,7 @@ def build_labelling(
     if engine is None:
         engine = make_relay(graph, backend=backend, **engine_kw)
     label_dist, meta_w, meta_dist = _build_labelling_arrays(
-        engine, landmarks, is_landmark, max_levels
-    )
+        engine, landmarks, is_landmark, max_levels)
     return LabellingScheme(
         landmarks=landmarks,
         lid=lid,
@@ -202,19 +173,48 @@ def build_labelling(
 # ---------------------------------------------------------------------------
 
 
+INSERT_BLOCK = 64    # inserts per call of the device insert test (one shape)
+
+
+@jax.jit
+def _inserts_affected(label_dist, meta_w, lm_dist, lid, is_landmark, ins, aff):
+    """``aff`` or the landmarks that ``affected_landmarks``' insert
+    criteria flag for the inserts ``ins`` (B, 2), read off the device
+    tables.  A ``(0, 0)`` pad row flags nothing (equal depths)."""
+    with scope("qbs.update"):
+        R = lm_dist.shape[0]
+        u, v = ins[:, 0], ins[:, 1]
+        du, dv = lm_dist[:, u], lm_dist[:, v]              # (R, B)
+        gap = jnp.abs(du - dv)
+
+        def bits(x):
+            """(R, B) reach_L and live L-contribution of the endpoints x."""
+            own = jnp.arange(R)[:, None] == lid[x][None, :]   # a root's own bit
+            labelled = (label_dist[x, :] < INF).T
+            lm_x = is_landmark[x][None, :]
+            reach = jnp.where(lm_x, (meta_w[:, lid[x]] < INF) | own, labelled)
+            return reach, jnp.where(lm_x, own, labelled)
+
+        (ru, cu), (rv, cv) = bits(u), bits(v)
+        tie = (gap == 1) & jnp.where(du < dv, cu & ~rv, cv & ~ru)
+        return aff | ((gap >= 2) | tie).any(axis=1)
+
+
 def affected_landmarks(
     scheme: LabellingScheme,
-    lm_dist: np.ndarray,
-    graph_new: Graph,
-    inserts: np.ndarray | None = None,
-    deletes: np.ndarray | None = None,
-) -> np.ndarray:
-    """``(R,)`` bool mask of landmarks whose BFS row an update batch touches.
+    lm_dist,
+    inserts: np.ndarray,
+    deletes: np.ndarray,
+    csr: tuple[np.ndarray, np.ndarray, np.ndarray],
+) -> jax.Array:
+    """``(R,)`` bool device mask of landmarks whose BFS row an update batch
+    touches.
 
-    ``lm_dist`` is the exact pre-update ``(R, V)`` distance table,
-    ``graph_new`` the post-batch graph, and ``inserts``/``deletes`` the
-    *effective* delta (insert-of-absent / delete-of-present edges only —
-    ``QbSIndex.apply_update`` filters).  Per landmark r and edge (a, b)
+    ``lm_dist`` is the exact pre-update ``(R, V)`` int32 distance table
+    (where it lives), ``inserts``/``deletes`` the *effective* delta
+    (insert-of-absent / delete-of-present edges only —
+    ``QbSIndex.apply_update`` filters) and ``csr`` the post-batch graph's
+    ``(indptr, src, dst)`` on the host.  Per landmark r and edge (a, b)
     with a the endpoint nearer r, the criteria are exact, not heuristic:
 
     * ``|d(r,a) - d(r,b)| >= 2`` insert: distances shorten — affected.
@@ -239,6 +239,12 @@ def affected_landmarks(
     what makes this tight on hub-heavy graphs: most diff==1 edges hang
     off landmark-shadowed vertices and flag nothing.
 
+    The insert criteria run on the device, in blocks of
+    ``INSERT_BLOCK`` (``_inserts_affected``): an insert-only batch copies
+    nothing to the host and waits for nothing.  The delete criterion
+    reads CSR rows, so it runs on the host, from host copies of the
+    tables.
+
     Batch-exactness: if no edge flags row r, induction over depth levels
     shows the BFS depth table and then the reach_L fixpoint of row r are
     preserved edge-by-edge (every insert ties or lands on a dead
@@ -246,15 +252,30 @@ def affected_landmarks(
     removes only dead or redundant contributions).  Flagged rows are
     recomputed exactly on ``graph_new``.
     """
-    lm = np.asarray(lm_dist)
+    aff = np.zeros((scheme.n_landmarks,), bool)
+    if len(deletes):
+        aff = _deletes_affected(scheme, np.asarray(lm_dist), deletes, csr)
+    ins = np.asarray(inserts, np.int32).reshape(-1, 2)
+    ins = np.concatenate([ins, np.zeros((-len(ins) % INSERT_BLOCK, 2), np.int32)])
+    aff = jnp.asarray(aff)
+    for lo in range(0, len(ins), INSERT_BLOCK):
+        aff = _inserts_affected(scheme.label_dist, scheme.meta_w, lm_dist,
+                                scheme.lid, scheme.is_landmark,
+                                jnp.asarray(ins[lo:lo + INSERT_BLOCK]), aff)
+    return aff
+
+
+def _deletes_affected(scheme: LabellingScheme, lm: np.ndarray, deletes,
+                      csr) -> np.ndarray:
+    """``affected_landmarks``' delete criterion on host copies of the
+    tables: the ``(R,)`` bool mask."""
     R = lm.shape[0]
     aff = np.zeros((R,), bool)
     label = np.asarray(scheme.label_dist)        # (V, R)
     meta_w = np.asarray(scheme.meta_w)           # (R, R)
     lid = np.asarray(scheme.lid)
     is_lm = np.asarray(scheme.is_landmark)
-    indptr = np.asarray(graph_new.indptr)
-    dst = np.asarray(graph_new.dst)
+    indptr, _, dst = csr
 
     def reach(x: int) -> np.ndarray:
         """(R,) reach_L[r, x]: a landmark-interior-free shortest r-x path."""
@@ -272,23 +293,6 @@ def affected_landmarks(
             return out
         return label[x, :] < INF
 
-    def _pairs(arr):
-        if arr is None:
-            return ()
-        arr = np.asarray(arr, np.int64).reshape(-1, 2)
-        return [(int(a), int(b)) for a, b in arr]
-
-    for u, v in _pairs(inserts):
-        du, dv = lm[:, u], lm[:, v]
-        gap = np.abs(du - dv)
-        rows = gap >= 2
-        one = gap == 1
-        if one.any():
-            cu, cv, ru, rv = contrib(u), contrib(v), reach(u), reach(v)
-            a_is_u = du < dv                     # a = nearer endpoint
-            rows = rows | (one & np.where(a_is_u, cu & ~rv, cv & ~ru))
-        aff |= rows
-
     def _pred_keep(x: int, dx: np.ndarray):
         """For farther endpoint x: (R,) has-surviving-shortest-predecessor
         and (R,) some survivor still carries a live L-contribution."""
@@ -304,7 +308,7 @@ def affected_landmarks(
             contrib_nb[lid[nb[j]], j] = True             # roots relay own bit
         return at_depth.any(axis=1), (at_depth & contrib_nb).any(axis=1)
 
-    for u, v in _pairs(deletes):
+    for u, v in np.asarray(deletes, np.int64).reshape(-1, 2).tolist():
         du, dv = lm[:, u], lm[:, v]
         one = np.abs(du - dv) == 1               # real edges: gap <= 1
         if not one.any():
@@ -319,82 +323,103 @@ def affected_landmarks(
     return aff
 
 
-def update_labelling(
-    graph_new: Graph,
-    scheme: LabellingScheme,
-    lm_dist: np.ndarray,
-    inserts: np.ndarray | None = None,
-    deletes: np.ndarray | None = None,
-    *,
-    max_levels: int = 256,
-    backend: str = "segment",
-    engine: FrontierEngine | None = None,
-    churn_threshold: float = 0.5,
-    **engine_kw,
-) -> tuple[LabellingScheme | None, np.ndarray | None, dict]:
-    """Incrementally maintain a labelling across one edge-update batch.
+def row_widths(k_max: int) -> tuple[int, ...]:
+    """The widths an affected set of 1..``k_max`` landmarks is padded to:
+    {1, 2, 3, 4, 6, 8, 12, ...} (powers of two and their 1.5x midpoints,
+    two shapes an octave) capped at ``k_max``, so a relabel's cost follows
+    the affected count with few shapes."""
+    def pad(k):
+        p = 1 << (k - 1).bit_length()
+        return p // 4 * 3 if k <= p // 4 * 3 else p
+    return tuple(sorted({min(pad(k), k_max) for k in range(1, k_max + 1)}))
 
-    Returns ``(scheme_new, lm_dist_new, info)`` where both tables are
-    bit-identical to a fresh ``build_labelling`` on ``graph_new`` (the
-    property-harness contract).  When the affected fraction exceeds
-    ``churn_threshold`` the incremental path loses to a rebuild; the
-    function returns ``(None, None, info)`` with ``info["full_rebuild"]``
-    set and the caller rebuilds.  ``info["affected"]`` holds the affected
-    landmark indices either way.
-    """
-    lm = np.asarray(lm_dist, np.int32)
+
+@partial(jax.jit, static_argnames=("k_max", "max_levels"))
+def _update_tables(engine, landmarks, is_landmark, label_dist, meta_w,
+                   meta_dist, lm_dist, aff, k_max: int, max_levels: int):
+    """One epoch's tables on ``engine``'s graph: the old ones where no
+    landmark is affected, the affected rows recomputed where at most
+    ``k_max`` are (padded to a ``row_widths`` width with a duplicate,
+    which recomputes identical values), the whole build past that.  One
+    program a graph shape: ``lax.switch`` runs one branch."""
+    R = landmarks.shape[0]
+    widths = row_widths(k_max)
+    with scope("qbs.update"):
+        n = aff.sum(dtype=jnp.int32)
+
+        def keep():
+            return label_dist, meta_w, meta_dist, lm_dist
+
+        def rows(w):
+            idx = jnp.nonzero(aff, size=w, fill_value=R)[0]
+            idx = jnp.where(idx == R, idx[0], idx)
+            with scope("qbs.update.relabel"):
+                depth, reach_l = _bfs_rows(engine, landmarks[idx], is_landmark,
+                                           max_levels)
+            valid = reach_l & (~is_landmark)[None, :]
+            label = label_dist.at[:, idx].set(jnp.where(valid, depth, INF).T)
+            # Raw meta values are symmetric (reach_L is a symmetric
+            # property), and an entry (i, j) only changes when d(r_i, r_j)
+            # or its L-bit moves, which flags *both* rows.  So scattering
+            # the recomputed rows into both the rows and the columns of the
+            # affected set, resetting its diagonal to INF and re-harmonizing
+            # with the transpose reproduces the fresh build's meta_w.
+            raw = jnp.where(reach_l[:, landmarks], depth[:, landmarks], INF)
+            mw = meta_w.at[idx, :].set(raw).at[:, idx].set(raw.T)
+            mw = mw.at[idx, idx].set(INF)
+            mw = jnp.minimum(mw, mw.T)
+            return label, mw, meta_apsp(mw), lm_dist.at[idx].set(depth)
+
+        def full():
+            with scope("qbs.update.relabel"):
+                return _labelling_arrays(engine, landmarks, is_landmark,
+                                         max_levels)
+
+        def int32(fn, *a):
+            return lambda: tuple(jnp.asarray(t, jnp.int32) for t in fn(*a))
+
+        branch = jnp.where(n == 0, 0, jnp.where(
+            n > k_max, len(widths) + 1,
+            1 + jnp.searchsorted(jnp.asarray(widths, jnp.int32), n)))
+        tables = jax.lax.switch(
+            branch, [int32(keep), *(int32(rows, w) for w in widths), int32(full)])
+        return tables, n, n > k_max
+
+
+def update_tables(engine: FrontierEngine, scheme: LabellingScheme, lm_dist,
+                  aff, *, churn_threshold: float = 0.5,
+                  max_levels: int = 256) -> tuple[LabellingScheme, jax.Array, dict]:
+    """Maintain a labelling across one edge-update batch on the device.
+
+    ``aff`` is the batch's ``(R,)`` affected-landmark mask
+    (``affected_landmarks``) and ``engine`` relays over the post-update
+    graph.  Returns
+    ``(scheme_new, lm_dist_new, info)``, both tables bit-identical to a
+    fresh ``build_labelling`` on that graph (the property-harness
+    contract), all device arrays: nothing waits for the device.  Where
+    more than ``churn_threshold`` of the landmarks are affected the
+    incremental path loses to a rebuild and the program rebuilds.
+    ``info`` holds ``affected``, ``n_affected`` and ``full_rebuild`` as
+    device values; ``update_path`` names the path."""
     R = scheme.n_landmarks
-    aff = affected_landmarks(scheme, lm, graph_new, inserts, deletes)
-    idx = np.nonzero(aff)[0].astype(np.int32)
-    info = {"affected": idx, "full_rebuild": False, "n_affected": int(idx.size)}
-    if idx.size == 0:
-        return scheme, lm, info
-    if idx.size > churn_threshold * R:
-        info["full_rebuild"] = True
-        return None, None, info
+    k_max = max(0, min(R, int(churn_threshold * R)))
+    aff = jnp.asarray(aff, bool)
+    (label, mw, md, lm), n, full = _update_tables(
+        engine, scheme.landmarks, scheme.is_landmark, scheme.label_dist,
+        scheme.meta_w, scheme.meta_dist, jnp.asarray(lm_dist, jnp.int32), aff,
+        k_max, max_levels)
+    return (scheme._replace(label_dist=label, meta_w=mw, meta_dist=md), lm,
+            {"affected": aff, "n_affected": n, "full_rebuild": full})
 
-    if engine is None:
-        engine = make_relay(graph_new, backend=backend, **engine_kw)
-    # Pad the root subset to the pad_width ladder so the jit cache sees a
-    # log-bounded set of shapes; duplicate rows recompute identical values,
-    # so scattering the padded set (duplicates included) is exact.
-    K = int(idx.size)
-    k_pad = pad_width(K)
-    idx_pad = np.concatenate([idx, np.full((k_pad - K,), idx[0], np.int32)])
-    roots_pad = np.asarray(scheme.landmarks)[idx_pad]
-    depth, label_cols, meta_rows = _build_labelling_rows(
-        engine, jnp.asarray(roots_pad, jnp.int32), scheme.landmarks,
-        scheme.is_landmark, max_levels)
-    # label_cols stays on device: the (V, R) table is scattered in place
-    # rather than round-tripped through the host.
-    label_dist = jnp.asarray(scheme.label_dist).at[
-        :, jnp.asarray(idx_pad)].set(label_cols)
-    depth = np.asarray(depth)[:K]              # (K, V) — new lm_dist rows
-    meta_rows = np.asarray(meta_rows)[:K]      # (K, R) raw, diag carries 0
-    # Raw meta values are symmetric (reach_L is a symmetric property), and
-    # an entry (i, j) only changes when d(r_i, r_j) or its L-bit moves —
-    # which flags *both* rows.  So scattering the recomputed rows into both
-    # the rows and columns of the affected set, resetting the (affected)
-    # diagonal to INF and re-harmonizing with the transpose reproduces the
-    # fresh build's meta_w exactly.
-    meta_w = np.asarray(scheme.meta_w).copy()
-    meta_w[idx, :] = meta_rows
-    meta_w[:, idx] = meta_rows.T
-    meta_w[idx, idx] = INF
-    meta_w = np.minimum(meta_w, meta_w.T)
-    meta_dist = _meta_apsp_j(jnp.asarray(meta_w))
 
-    lm_new = lm.copy()
-    lm_new[idx] = depth
-    scheme_new = LabellingScheme(
-        landmarks=scheme.landmarks,
-        lid=scheme.lid,
-        is_landmark=scheme.is_landmark,
-        label_dist=label_dist,
-        meta_w=jnp.asarray(meta_w),
-        meta_dist=meta_dist,
-    )
-    return scheme_new, lm_new, info
+def update_path(info: dict) -> str:
+    """How an epoch's labels were made, from ``update_tables``' ``info``:
+    ``"noop"`` (no landmark's row moved; the tables are the old ones),
+    ``"incremental"`` (the affected rows recomputed) or ``"rebuild"``.
+    Waits for the device where the values are still being computed."""
+    if not int(info["n_affected"]):
+        return "noop"
+    return "rebuild" if bool(info["full_rebuild"]) else "incremental"
 
 
 def labelling_size_bytes(scheme: LabellingScheme) -> dict:

@@ -28,6 +28,7 @@ packed pipeline stays bit-identical to the unpacked oracle.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -35,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .graph import INF
+from ..tracing import scope
 
 # Escape-hatch ladder: narrowest first; the dtype max is the INF sentinel.
 _PACK_DTYPES = (np.uint8, np.uint16)
@@ -67,19 +69,6 @@ def choose_pack_dtype(*arrays) -> np.dtype:
     raise ValueError(
         f"max finite distance {m} collides with the uint16 sentinel "
         f"{sentinel_of(np.uint16)}; no packed layout fits")
-
-
-def pad_width(n: int) -> int:
-    """Smallest ladder width >= ``n`` from {1, 2, 3, 4, 6, 8, 12, 16, ...}
-    (powers of two plus their 1.5x midpoints — two shapes per octave).
-    The incremental-update path pads its affected-landmark sets to these
-    widths so the jit/eager compile caches see a log-bounded family of
-    shapes instead of one entry per distinct ``|affected|``."""
-    if n <= 1:
-        return 1
-    p = 1 << (n - 1).bit_length()
-    mid = p // 4 * 3
-    return mid if n <= mid else p
 
 
 def pack_dist(a, dtype) -> jax.Array:
@@ -154,59 +143,23 @@ def pack_labelling(scheme, lm_dist=None, *, dtype=None) -> PackedLabels:
     )
 
 
-def patch_packed(
-    old: PackedLabels,
-    scheme,
-    lm_dist,
-    affected: np.ndarray,
-) -> PackedLabels:
-    """Patch a ``PackedLabels`` after an incremental labelling update.
+@partial(jax.jit, static_argnames=("dtype",))
+def _pack_tables(label_dist, meta_w, meta_dist, lm_dist, dtype):
+    with scope("qbs.update"):
+        sent = sentinel_of(dtype)
+        return PackedLabels(*(jnp.where(a >= INF, sent, a).astype(dtype)
+                              for a in (label_dist, meta_w, meta_dist, lm_dist)))
 
-    ``affected`` holds the landmark indices whose rows/columns changed
-    (``update_labelling``'s ``info["affected"]``).  The dtype is re-derived
-    from the *new* tables so the result is bit-identical to a fresh
-    ``pack_labelling`` — including the uint8 -> uint16 escape hatch, which
-    forces a full repack when the measured diameter crosses the sentinel
-    (and the narrowing back when it recedes).  Otherwise only the affected
-    label columns and lm_dist rows are scattered; the (R, R) meta tables
-    are tiny and repacked whole.
 
-    Hot-path discipline: the dtype probe and the label-column gather/pack/
-    scatter all run on device (one scalar sync), never round-tripping the
-    (V, R) table through the host, and the scatter width is padded to the
-    ``pad_width`` ladder (duplicated indices rewrite identical values) so
-    the compile caches stay log-bounded across epochs.
-    """
-    m = jnp.asarray(0, jnp.int32)
-    for a in (scheme.label_dist, scheme.meta_w, scheme.meta_dist, lm_dist):
-        a = jnp.asarray(a)
-        m = jnp.maximum(m, jnp.where(a < INF, a, 0).max().astype(jnp.int32))
-    m = int(m)
-    for dtype in _PACK_DTYPES:
-        if m < sentinel_of(dtype):
-            dtype = np.dtype(dtype)
-            break
-    else:
-        raise ValueError(
-            f"max finite distance {m} collides with the uint16 sentinel "
-            f"{sentinel_of(np.uint16)}; no packed layout fits")
-    if dtype != old.dtype or old.lm_dist is None:
-        return pack_labelling(scheme, lm_dist=lm_dist, dtype=dtype)
-    aff = np.asarray(affected, np.int32)
-    k_pad = pad_width(int(aff.size))
-    aff = np.concatenate([aff, np.full((k_pad - aff.size,), aff[0], np.int32)])
-    idx = jnp.asarray(aff)
-    sent = sentinel_of(dtype)
-    cols = jnp.asarray(scheme.label_dist)[:, idx]
-    cols = jnp.where(cols >= INF, sent, cols).astype(dtype)
-    rows = jnp.asarray(np.asarray(lm_dist)[aff, :])
-    rows = jnp.where(rows >= INF, sent, rows).astype(dtype)
-    return PackedLabels(
-        label_dist=old.label_dist.at[:, idx].set(cols),
-        meta_w=pack_dist(scheme.meta_w, dtype),
-        meta_dist=pack_dist(scheme.meta_dist, dtype),
-        lm_dist=old.lm_dist.at[idx, :].set(rows),
-    )
+def pack_tables(scheme, lm_dist, dtype) -> PackedLabels:
+    """``pack_labelling`` at ``dtype`` as one device program, for the
+    update path: no table leaves the device and nothing waits for it.
+    Unlike ``pack_dist`` it checks nothing, so ``dtype`` must be one
+    ``choose_pack_dtype`` gives for these tables (``QbSIndex.apply_update``
+    probes them, or keeps the narrowest dtype where no distance can have
+    grown)."""
+    return _pack_tables(scheme.label_dist, scheme.meta_w, scheme.meta_dist,
+                        lm_dist, dtype=np.dtype(dtype))
 
 
 def packed_size_bytes(packed: PackedLabels) -> dict:

@@ -52,14 +52,23 @@ import numpy as np
 from .frontier import bfs_depths_batch, make_relay
 from .graph import (
     INF,
+    EdgeSlots,
     Graph,
     apply_edge_updates,
-    edge_keys,
-    edge_set,
     select_landmarks,
 )
-from .labelling import LabellingScheme, build_labelling, update_labelling
-from .packing import pack_labelling, patch_packed, widen_dist
+from .labelling import (
+    LabellingScheme,
+    affected_landmarks,
+    build_labelling,
+    update_tables,
+)
+from .packing import (
+    choose_pack_dtype,
+    pack_labelling,
+    pack_tables,
+    widen_dist,
+)
 from .search import (
     Query,
     guided_search,
@@ -222,7 +231,9 @@ class QbSIndex:
                  max_levels: int = 512, max_chain: int = 512, chunk: int = 32,
                  use_pallas: bool = True, backend: str = "segment",
                  engine_opts: dict | None = None,
-                 epoch: int = 0, lm_dist=None, packed=None):
+                 epoch: int = 0, lm_dist=None, packed=None,
+                 slots: EdgeSlots | None = None, unreached=None,
+                 full_engine=None, landmark_host=None):
         self.graph = graph
         self.scheme = scheme
         self.max_levels = max_levels
@@ -241,16 +252,29 @@ class QbSIndex:
 
         engine_opts = engine_opts or {}
         self._engine_opts = dict(engine_opts)
+        # Host mirror of the graph's live edge slots (``graph.EdgeSlots``):
+        # ``apply_update`` hands each epoch's index the mirror its graph was
+        # built from, so no update copies the CSR back from the device.  A
+        # built index makes it at its first update.
+        self._slots = slots
+        relay_kw = dict(engine_opts)
+        if slots is not None:
+            relay_kw["host"] = slots.csr
         # (R, V) exact vertex-to-landmark distances, a pure function of the
         # labelling — built once here so the landmark lane steps gather
         # rows instead of re-reducing the label matrix every chunk.
         # ``apply_update`` passes the incrementally-maintained table in
         # (bit-identical: both are exact BFS distances with the same INF).
+        # The table may still be in the making on the device (an update's);
+        # ``_lm_dist_host`` copies it out where the host needs it.
         if lm_dist is None:
             lm_dist = _dists_to_landmark_batch(
                 scheme.label_dist, scheme.meta_dist, scheme.lid,
                 scheme.is_landmark, jnp.arange(scheme.n_landmarks))
-        self._lm_dist_host = np.asarray(lm_dist, np.int32)
+        self._lm_table = lm_dist
+        # (V,) bool: vertices some landmark does not reach (``apply_update``
+        # passes its own, computed lazily otherwise).
+        self._unreached_mask = unreached
         # The packed label tables (uint8/uint16 + INF sentinel, dtype chosen
         # from the measured diameter — core.packing, DESIGN.md §10) are what
         # HBM holds; every jit consumer below widens gathered rows in
@@ -260,22 +284,38 @@ class QbSIndex:
         self.packed = packed
         self._lm_dist = self.packed.lm_dist
         self.ctx = make_search_context(graph, scheme, backend=backend,
-                                       packed=self.packed, **engine_opts)
+                                       packed=self.packed, **relay_kw)
         # Unmasked full-graph relay for the landmark-endpoint path (those
-        # shortest paths may pass *through* landmarks, so G- is wrong there).
-        self._full_engine = make_relay(graph, backend=backend, **engine_opts)
-        is_l = scheme.is_landmark
-        self._is_landmark_np = np.asarray(is_l)
-        self._lid_np = np.asarray(scheme.lid)
+        # shortest paths may pass *through* landmarks, so G- is wrong there)
+        # and for the update path's relabel.
+        if full_engine is None:
+            full_engine = make_relay(graph, backend=backend, **relay_kw)
+        self._full_engine = full_engine
+        # host copies of the pinned landmark set, handed on by each update
+        self._is_landmark_np, self._lid_np = landmark_host or (
+            np.asarray(scheme.is_landmark), np.asarray(scheme.lid))
         self._service = None
 
         self._search_batch = _make_search_batch(
             graph.n_vertices, max_levels, max_chain, use_pallas)
 
     @cached_property
+    def _lm_dist_host(self) -> np.ndarray:
+        """(R, V) int32 vertex-to-landmark distances on the host."""
+        return np.asarray(self._lm_table, np.int32)
+
+    @property
+    def _unreached(self) -> np.ndarray:
+        if self._unreached_mask is None:
+            self._unreached_mask = (self._lm_dist_host >= INF).any(axis=0)
+        return self._unreached_mask
+
+    @cached_property
     def _rev_edge(self) -> np.ndarray:
-        """Lazy: an O(E log E) host sort the epoch-advance path defers to
-        first query time (update latency should not pay for it)."""
+        """Lazy: a built index's is an O(E log E) host sort, deferred to
+        first query time; an updated index's comes from its mirror."""
+        if self._slots is not None:
+            return self._slots.rev_slots()
         return _reverse_edge_map(
             np.asarray(self.graph.src), np.asarray(self.graph.dst),
             self.graph.n_vertices)
@@ -324,55 +364,100 @@ class QbSIndex:
         epoch (``self`` is untouched — in-flight chunks pinned to it stay
         bit-consistent with their admission epoch).
 
+        The batch merges into the host mirror of the edge slots
+        (``graph.apply_edge_updates``), and the new CSR is uploaded once.
         The landmark set is pinned at epoch 0; labels are maintained by
-        recomputing only the affected landmarks' BFS rows on the post-update
-        graph (``labelling.update_labelling``) and patching the packed
-        tables in place (``packing.patch_packed``).  Past
-        ``churn_threshold`` (affected fraction of R) the incremental path
-        loses to a rebuild and we rebuild outright.  Either way the new
-        index's tables are bit-identical to a fresh build on the new graph
-        with the same landmarks — the property-harness contract.
-        """
-        # Reduce the request to its effective delta (insert-of-present and
-        # delete-of-absent edges are no-ops) so phantom edges never flag a
-        # landmark for recompute.
-        n_v = self.graph.n_vertices
-        cur = edge_set(self.graph)
-        present = cur[:, 0] * np.int64(n_v) + cur[:, 1]
-        ins0 = edge_keys(inserts, n_v) if inserts is not None else \
-            np.zeros((0,), np.int64)
-        del0 = edge_keys(deletes, n_v) if deletes is not None else \
-            np.zeros((0,), np.int64)
-        ins = ins0[~np.isin(ins0, present)]           # insert-of-absent only
-        dels = del0[np.isin(del0, present)]           # delete-of-present only
-        dels = dels[~np.isin(dels, ins0)]             # inserts win a tie
-        ins_arr = np.stack([ins // n_v, ins % n_v], axis=1)
-        del_arr = np.stack([dels // n_v, dels % n_v], axis=1)
+        recomputing only the affected landmarks' BFS rows on the
+        post-update graph, or the whole build past ``churn_threshold``
+        (affected fraction of R), where the incremental path loses to a
+        rebuild (``labelling.update_tables``).  Either way the new index's
+        tables are bit-identical to a fresh build on the new graph with the
+        same landmarks — the property-harness contract.
 
-        graph_new = apply_edge_updates(self.graph, ins_arr, del_arr)
-        scheme_new, lm_new, info = update_labelling(
-            graph_new, self.scheme, self._lm_dist_host, ins_arr, del_arr,
-            backend=self.backend, churn_threshold=churn_threshold,
-            **self._engine_opts)
+        An insert-only batch whose endpoints every landmark reaches, on
+        tables in the narrowest packing, never waits for the device: its
+        affected set is computed there (``labelling.affected_landmarks``),
+        and no distance can grow, so the packing stays.  The new epoch's
+        programs queue behind the chunks in flight, and the chunks admitted
+        under it queue behind them.  Other batches read the affected set
+        and the packing off host copies of the tables, which wait for the
+        device.  ``last_update_info`` holds ``update_tables``' info
+        (``labelling.update_path`` names its path) and whether the
+        edge-slot capacity took a step (``warm_update_path``).
+        """
+        if self._slots is None:
+            self._slots = EdgeSlots.of_graph(self.graph)
+        n_v = self.graph.n_vertices
+        slots, ins, dels = apply_edge_updates(self._slots, inserts, deletes)
         kw = dict(max_levels=self.max_levels, max_chain=self.max_chain,
                   chunk=self.chunk, use_pallas=self.use_pallas,
                   backend=self.backend, engine_opts=self._engine_opts,
                   epoch=self.epoch + 1)
-        if scheme_new is None:  # churn above threshold: full rebuild
-            scheme_new = build_labelling(
-                graph_new, np.asarray(self.scheme.landmarks),
-                backend=self.backend, **self._engine_opts)
-            new = QbSIndex(graph_new, scheme_new, **kw)
+        if not (ins.size or dels.size):   # nothing to apply: the same tables
+            new = QbSIndex(self.graph, self.scheme, lm_dist=self._lm_table,
+                           packed=self.packed, slots=self._slots,
+                           unreached=self._unreached,
+                           full_engine=self._full_engine,
+                           landmark_host=(self._is_landmark_np, self._lid_np),
+                           **kw)
+            new.last_update_info = {"n_affected": 0, "full_rebuild": False,
+                                    "capacity_step": False}
+            return new
+        ins = np.stack([ins // n_v, ins % n_v], axis=1)
+        dels = np.stack([dels // n_v, dels % n_v], axis=1)
+        graph_new = slots.graph()
+        engine = make_relay(graph_new, backend=self.backend, host=slots.csr,
+                            **self._engine_opts)
+        aff = affected_landmarks(self.scheme, self._lm_table, ins, dels,
+                                 slots.csr)
+        scheme_new, lm_new, info = update_tables(
+            engine, self.scheme, self._lm_table, aff,
+            churn_threshold=churn_threshold)
+        if (not dels.size and self.packed.dtype == np.uint8
+                and not self._unreached[ins].any()):
+            # distances only shrink where every landmark reached both ends
+            dtype, unreached = self.packed.dtype, self._unreached
         else:
-            if info["n_affected"]:
-                packed_new = patch_packed(
-                    self.packed, scheme_new, lm_new, info["affected"])
-            else:
-                packed_new = self.packed  # labels untouched; only CSR moved
-            new = QbSIndex(graph_new, scheme_new, lm_dist=lm_new,
-                           packed=packed_new, **kw)
+            lm_host = np.asarray(lm_new)
+            dtype = choose_pack_dtype(scheme_new.label_dist, scheme_new.meta_w,
+                                      scheme_new.meta_dist, lm_host)
+            unreached = (lm_host >= INF).any(axis=0)
+        new = QbSIndex(graph_new, scheme_new, lm_dist=lm_new,
+                       packed=pack_tables(scheme_new, lm_new, dtype),
+                       slots=slots, unreached=unreached, full_engine=engine,
+                       landmark_host=(self._is_landmark_np, self._lid_np),
+                       **kw)
+        info["capacity_step"] = graph_new.n_edges != self.graph.n_edges
         new.last_update_info = info
         return new
+
+    @property
+    def n_live_slots(self) -> int:
+        """Edge slots that hold an edge (the rest of ``graph.n_edges`` is
+        padding the capacity ladder keeps free)."""
+        if self._slots is not None:
+            return self._slots.n_live
+        return int(np.count_nonzero(
+            np.asarray(self.graph.src) != np.asarray(self.graph.dst)))
+
+    def warm_update_path(self, churn_threshold: float = 0.5) -> None:
+        """Compile, and run once, every program the update path can take
+        at this graph's edge-slot capacity: the device insert test, the
+        table update (its three branches are one program) and the table
+        packing at each packed dtype.  They run on this epoch's own tables
+        with no landmark affected, and their results are dropped.  The
+        serving tier calls it when an update takes a capacity step, before
+        the new epoch serves (``ReplicaRouter.apply_update``,
+        ``StreamingService.submit_update``), so no later update of the
+        step compiles."""
+        aff = affected_landmarks(self.scheme, self._lm_table,
+                                 np.zeros((1, 2), np.int32),
+                                 np.zeros((0, 2), np.int32), None)
+        scheme, lm, _ = update_tables(self._full_engine, self.scheme,
+                                      self._lm_table, aff,
+                                      churn_threshold=churn_threshold)
+        for dtype in (np.uint8, np.uint16):
+            jax.block_until_ready(pack_tables(scheme, lm, dtype))
 
     # -- construction -------------------------------------------------------
 

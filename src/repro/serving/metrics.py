@@ -38,6 +38,14 @@ from typing import Callable, Iterable
 N_BUCKETS = 33
 _OVERFLOW = N_BUCKETS - 1
 
+# ``StreamingService.stats`` keys with their own exposition: an epoch's
+# labels take one of UPDATE_PATHS (``updates_<path>`` counts them, shown
+# as ``qbs_updates_total{path=...}``), and the gauges hold the last
+# installed epoch's affected landmarks and the graph's edge slots
+UPDATE_PATHS = ("incremental", "rebuild", "noop")
+UPDATE_GAUGES = ("update_affected_landmarks", "edge_slot_capacity",
+                 "edge_slots_live")
+
 
 def bucket_of(us: float) -> int:
     """Bucket index for a latency in microseconds (log2 edges)."""
@@ -123,6 +131,9 @@ class MetricsRegistry:
             return list(self._sources)
 
     def _snapshot_one(self, st) -> dict:
+        # the update paths the device has settled since the last cut
+        if hasattr(st, "count_updates"):
+            st.count_updates()
         # one consistent cut per source: everything below reads under the
         # service's own lock, the same lock its mutators hold (QBS005)
         with st._lock:
@@ -168,7 +179,13 @@ class MetricsRegistry:
         for name, s in sorted(snap.items()):
             lab = f'service="{name}"'
             for k, v in sorted(s["stats"].items()):
-                lines.append(f"qbs_{k}_total{{{lab}}} {v}")
+                if k in UPDATE_GAUGES:
+                    lines.append(f"qbs_{k}{{{lab}}} {v}")
+                elif k.startswith("updates_"):
+                    lines.append(
+                        f'qbs_updates_total{{{lab},path="{k[8:]}"}} {v}')
+                elif k != "updates":      # the sum of the path series
+                    lines.append(f"qbs_{k}_total{{{lab}}} {v}")
             lines.append(f"qbs_pending{{{lab}}} {s['n_pending']}")
             lines.append(f"qbs_inflight{{{lab}}} {s['n_inflight']}")
             lines.append(f"qbs_chunk_width{{{lab}}} {s['chunk']}")

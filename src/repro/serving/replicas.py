@@ -133,6 +133,7 @@ class ReplicaRouter:
             "updates": 0,         # epoch advances fanned out (§13)
         }, what="ReplicaRouter.stats")
         self._lock = san.lock if san is not None else threading.RLock()
+        self._update_lock = threading.Lock()    # one epoch advance at a time
         self._qbs = san
 
     def __setattr__(self, name, value):
@@ -303,17 +304,25 @@ class ReplicaRouter:
         """Advance the whole tier one epoch: compute the next index
         *once* (incremental label maintenance on the routed index) and
         install it on every replica — live and draining alike, so a
-        restored replica is never behind the tier's epoch.  Serialized
-        under the router lock: concurrent updates install in epoch order
-        on every replica (router -> replica is the tier's one lock
-        order).  Returns the new index."""
-        with self._lock:
+        restored replica is never behind the tier's epoch.  Updates are
+        serialized by their own lock, so they install in epoch order on
+        every replica; the next index is computed outside the router
+        lock, which routing needs, and handed to every replica under it
+        (``StreamingService.offer_index``, which never waits for a
+        replica's scheduler; update -> router is the tier's lock order).
+        An update that takes an edge-slot capacity step warms the update
+        path at the new capacity before it installs
+        (``QbSIndex.warm_update_path``).  Returns the new index."""
+        with self._update_lock:
             new = self.index.apply_update(inserts=inserts, deletes=deletes,
                                           churn_threshold=churn_threshold)
-            self.index = new
-            for rep in self.replicas:
-                rep.install_index(new)
-            self.stats["updates"] += 1
+            if new.last_update_info.get("capacity_step"):
+                new.warm_update_path(churn_threshold)
+            with self._lock:
+                self.index = new
+                for rep in self.replicas:
+                    rep.offer_index(new)
+                self.stats["updates"] += 1
         return new
 
     # -- lifecycle -----------------------------------------------------------

@@ -71,10 +71,11 @@ from typing import Iterable, Iterator, Sequence
 import numpy as np
 
 from ..core.graph import INF
+from ..core.labelling import update_path
 from ..tracing import span
 from . import debug
 from .clock import ManualClock, SystemClock  # noqa: F401  (re-export)
-from .metrics import LatencyHistogram
+from .metrics import UPDATE_PATHS, LatencyHistogram
 from .planner import (
     LANE_GENERAL,
     LANE_LANDMARK_PAIR,
@@ -84,6 +85,13 @@ from .planner import (
     plan_from_pairs,
 )
 from .service import ServingService, _NO_EDGES, fetch_chunk
+
+
+def _edge_slots(index) -> tuple[int, int]:
+    """``(capacity, live)`` edge slots of an index's graph (a sharded
+    index, which takes no update, holds no padding)."""
+    cap = index.graph.n_edges
+    return cap, getattr(index, "n_live_slots", cap)
 
 
 @dataclass(frozen=True)
@@ -285,8 +293,24 @@ class StreamingService:
             "handed_off": 0,       # pending pairs exported to a peer
                                    # replica (handoff_pending)
             "updates": 0,          # epoch advances installed (§13)
+            # ... by the path the labels took (UPDATE_PATHS)
+            **{f"updates_{p}": 0 for p in UPDATE_PATHS},
+            # gauges (metrics.UPDATE_GAUGES): the last installed epoch's
+            # affected landmarks, and the graph's edge slots
+            "update_affected_landmarks": 0,
+            **dict(zip(("edge_slot_capacity", "edge_slots_live"),
+                       _edge_slots(self.index))),
             "result_bytes": 0,     # chunk results copied device -> host
         }, what="StreamingService.stats")
+        # installed epochs' update infos whose path the device has not
+        # settled yet (``count_updates``)
+        self._uncounted: deque = box.deque(
+            what="StreamingService._uncounted")
+        # next-epoch indexes handed over by ``offer_index`` and not yet
+        # installed: appended off the lock (a deque append is atomic),
+        # installed under it; ``_latest`` is the newest index handed over
+        self._offered: deque = deque()
+        self._latest = self.index
         # waits are wall-clock (injected-clock) seconds from submit to
         # admission — the queueing latency the deadline bounds; bounded
         # deques so a long-running service cannot grow host memory
@@ -360,6 +384,7 @@ class StreamingService:
         us = np.asarray(us, np.int32).reshape(-1)
         vs = np.asarray(vs, np.int32).reshape(-1)
         with self._lock:
+            self._adopt_offered()
             if qos is None:
                 ci = 0
             elif qos in self._cls_index:
@@ -469,6 +494,7 @@ class StreamingService:
     def drain(self) -> None:
         """Admit every pending pair and resolve all in-flight work."""
         with self._lock:
+            self._adopt_offered()
             self._pump(force=True)
             self._sync_until(0)
             self._arm_timer()
@@ -478,6 +504,7 @@ class StreamingService:
         the current (injected) clock without submitting new traffic.  A
         no-op on an empty backlog — stale timer wakeups are safe."""
         with self._lock:
+            self._adopt_offered()
             self._pump()
             self._arm_timer()
 
@@ -522,6 +549,7 @@ class StreamingService:
                 f"cannot adopt under unknown qos class {qos!r}; replicas "
                 f"must share one QoS config")
         with self._lock:
+            self._adopt_offered()
             ci = self._cls_index[qos]
             cstat = self.qos_stats[qos]
             now = self.clock.now()
@@ -604,27 +632,81 @@ class StreamingService:
         advance the epoch.  The next epoch's index is computed *outside*
         the scheduler lock (incremental label maintenance —
         ``QbSIndex.apply_update`` — can take many milliseconds; serving
-        keeps running on the current epoch meanwhile), then swapped in
-        atomically via ``install_index``.  Returns the new index.
+        keeps running on the current epoch meanwhile), then handed over
+        with ``offer_index``.  Returns the new index.
 
         Consistency: chunks already dispatched resolve under their
         admission epoch (their device programs hold the old tables);
         pairs still pending admit under the new epoch at their next
-        flush; the caches never cross epochs (keys carry the epoch)."""
-        new = self.index.apply_update(inserts=inserts, deletes=deletes,
-                                      churn_threshold=churn_threshold)
-        self.install_index(new)
+        flush; the caches never cross epochs (keys carry the epoch).  An
+        update that takes an edge-slot capacity step warms the update path
+        at the new capacity first (``QbSIndex.warm_update_path``)."""
+        new = self._latest.apply_update(inserts=inserts, deletes=deletes,
+                                        churn_threshold=churn_threshold)
+        if new.last_update_info.get("capacity_step"):
+            new.warm_update_path(churn_threshold)
+        self.offer_index(new)
         return new
 
     def install_index(self, index) -> None:
         """Install a pre-computed next-epoch index under the scheduler
-        lock — the fan-out hook ``ReplicaRouter.apply_update`` uses to
-        advance every replica to the *same* index without computing the
-        update batch N times."""
+        lock, waiting for it.  Records the graph's edge slots
+        (``metrics.UPDATE_GAUGES``) and queues the epoch for
+        ``count_updates``.  The serving path hands indexes over with
+        ``offer_index``, which never waits."""
+        info = index.last_update_info
+        slots = _edge_slots(index)
         with self._lock:
             self.service.install_index(index)
             self.index = index
             self.stats["updates"] += 1
+            self.stats["edge_slot_capacity"], self.stats["edge_slots_live"] = slots
+            if "n_affected" in info:
+                self._uncounted.append(info)
+            self._count_settled()
+
+    def offer_index(self, index) -> None:
+        """Hand over a pre-computed next-epoch index without waiting for
+        the scheduler lock, which a thread blocked on the device holds for
+        up to a chunk — the fan-out hook ``ReplicaRouter.apply_update``
+        uses to advance every replica to the *same* index without
+        computing the update batch N times.  The index is installed at
+        once if the lock is free, and otherwise by the next entry point
+        (``submit_batch``, ``adopt``, ``drain``, ``poll``, the deadline
+        timer), each of which installs the offered indexes before it reads
+        the epoch.  So every read submitted after this returns is admitted,
+        and looked up in the cache, at the new epoch or a later one."""
+        self._offered.append(index)
+        self._latest = index
+        if self._lock.acquire(blocking=False):
+            try:
+                self._adopt_offered()
+            finally:
+                self._lock.release()
+
+    def _adopt_offered(self) -> None:  # qbslint: locked
+        while self._offered:
+            self.install_index(self._offered.popleft())
+
+    def count_updates(self) -> None:
+        """Count the installed epochs by the path their labels took
+        (``updates_<path>``) and set the affected-landmark gauge, for every
+        epoch, in install order, whose path the device has settled.  An
+        update's path is a device value (``QbSIndex.apply_update`` does not
+        wait for it), so the count never waits either: an epoch still in
+        the making is counted by a later call (``metrics.MetricsRegistry``
+        calls it before each snapshot)."""
+        with self._lock:
+            self._adopt_offered()
+            self._count_settled()
+
+    def _count_settled(self) -> None:  # qbslint: locked
+        while self._uncounted and all(
+                getattr(self._uncounted[0][k], "is_ready", lambda: True)()
+                for k in ("n_affected", "full_rebuild")):
+            info = self._uncounted.popleft()
+            self.stats[f"updates_{update_path(info)}"] += 1
+            self.stats["update_affected_landmarks"] = int(info["n_affected"])
 
     def close(self) -> None:
         """Drain outstanding work and disarm the deadline timer, so no
@@ -881,6 +963,7 @@ class StreamingService:
 
     def _on_timer(self, token) -> None:
         with self._lock:
+            self._adopt_offered()
             if token is self._timer_token:
                 self._timer = None
                 self._armed_for = None

@@ -46,6 +46,8 @@ SCOPES = (
     ("qbs.recover", "general_lane.recover_ms_per_query"),
     ("qbs.onesided.bfs", "onesided_lane.bfs_ms_per_query"),
     ("qbs.onesided.certify", "onesided_lane.certify_ms_per_query"),
+    ("qbs.update", "update.device_ms_per_epoch"),
+    ("qbs.update.relabel", "update.relabel_ms_per_epoch"),
 )
 
 

@@ -209,3 +209,34 @@ def test_sharded_serve_step_loops_have_no_scatter(topo, no_persistent_cache,
         assert {"qbs.bfs", "qbs.reverse"} <= found, found
     else:
         assert found == set(), found
+
+
+def test_update_programs_compile_for_v5e_with_their_scopes(
+        one_chip, no_persistent_cache, small_index):
+    """The update path's programs (the device insert test, the table
+    update with its keep / affected-rows / rebuild branches, the table
+    packing) compile for the chip and carry ``qbs.update``; the table
+    update's BFS loops carry ``qbs.update.relabel`` and relay by rows."""
+    from repro.core.labelling import _inserts_affected, _update_tables
+    from repro.core.packing import _pack_tables
+
+    idx = small_index
+    s = idx.scheme
+    lm = jnp.asarray(idx._lm_table, jnp.int32)
+    aff = jnp.zeros((s.n_landmarks,), bool)
+    text = _update_tables.lower(*_on_chip(
+        (idx._full_engine, s.landmarks, s.is_landmark, s.label_dist, s.meta_w,
+         s.meta_dist, lm, aff), one_chip),
+        k_max=2, max_levels=256).compile().as_text()
+    assert "qbs.update.relabel" in text
+    assert not [n for n in re.findall(r'op_name="([^"]*)"', text)
+                if "while/body/scatter" in n], text[:200]
+    texts = [text, _inserts_affected.lower(*_on_chip(
+        (s.label_dist, s.meta_w, lm, s.lid, s.is_landmark,
+         jnp.zeros((64, 2), jnp.int32), aff), one_chip)).compile().as_text()]
+    for dtype in (np.uint8, np.uint16):
+        texts.append(_pack_tables.lower(
+            *_on_chip((s.label_dist, s.meta_w, s.meta_dist, lm), one_chip),
+            dtype=np.dtype(dtype)).compile().as_text())
+    for text in texts:
+        assert "qbs.update" in text
