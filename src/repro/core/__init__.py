@@ -24,7 +24,7 @@ from .packing import (
 )
 from .distributed import ShardedLabels, distributed_build_sharded
 from .qbs import QbSIndex, SPGResult
-from .search import Query, SearchContext, SearchResult, guided_search, make_search_context
+from .search import Query, SearchContext, SearchResult, make_search_context, search_chunk
 from .sharded import ShardedIndex
 from .sketch import SketchBatch, compute_sketch_batch, d_top_only
 
@@ -63,7 +63,7 @@ __all__ = [
     "Query",
     "SearchContext",
     "SearchResult",
-    "guided_search",
+    "search_chunk",
     "SketchBatch",
     "compute_sketch_batch",
     "d_top_only",

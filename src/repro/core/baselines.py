@@ -27,7 +27,7 @@ import numpy as np
 from .frontier import bfs_depths, make_relay
 from .graph import INF, Graph
 from .qbs import SPGResult, _reverse_edge_map
-from .search import Query, guided_search, make_search_context
+from .search import Query, make_search_context, search_chunk
 
 # ---------------------------------------------------------------------------
 # Oracle
@@ -63,13 +63,13 @@ def bfs_spg(graph: Graph, u: int, v: int, max_levels: int = 256,
 
 @lru_cache(maxsize=8)
 def _bibfs_search_step(n_vertices: int, max_levels: int):
-    """One jitted, vmapped degenerate search per (V, max_levels).  The
+    """One jitted degenerate chunk search per (V, max_levels).  The
     search context rides in as a pytree argument, so every same-sized
     graph/backend shares this entry; constructing ``jax.jit`` inside
-    ``bibfs_spg_batch`` instead would recompile on every call (QBS004)."""
-    search = partial(guided_search, n_vertices=n_vertices,
-                     max_levels=max_levels, max_chain=1)
-    return jax.jit(jax.vmap(search, in_axes=(None, 0)))
+    ``bibfs_spg_batch`` instead would recompile on every call (QBS004).
+    With no landmark every sketch entry is INF, so recover gets no job."""
+    return jax.jit(partial(search_chunk, n_vertices=n_vertices,
+                           max_levels=max_levels, max_chain=1))
 
 
 def bibfs_spg_batch(graph: Graph, us, vs, max_levels: int = 512,

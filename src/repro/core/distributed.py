@@ -44,7 +44,7 @@ from .labelling import LabellingScheme, meta_apsp
 from .packing import PackedLabels, choose_pack_dtype, pack_dist, sentinel_of
 from .packing import pack_bits as _pack_bits
 from .packing import unpack_bits as _unpack_bits
-from .search import Query, SearchContext, guided_search
+from .search import Query, SearchContext, search_chunk
 from .sketch import compute_sketch_batch
 
 
@@ -725,10 +725,6 @@ def make_serve_step(
     bytes per device; ``compute_sketch_batch`` widens in registers) — the
     two are bit-identical."""
     axis_names = axis_names or tuple(mesh.axis_names)
-    searcher = partial(
-        guided_search, n_vertices=n_vertices,
-        max_levels=max_levels, max_chain=max_chain,
-    )
 
     def step(ctx, label_dist, meta_w, meta_dist, us, vs):
         lu = label_dist[us]
@@ -739,7 +735,7 @@ def make_serve_step(
             u=us, v=vs, d_top=sk.d_top, du_land=sk.du_land, dv_land=sk.dv_land,
             meta_edge=sk.meta_edge, d_star_u=sk.d_star_u, d_star_v=sk.d_star_v,
         )
-        res = jax.vmap(searcher, in_axes=(None, 0))(ctx, queries)
+        res = search_chunk(ctx, queries, n_vertices, max_levels, max_chain)
         return res.edge_mask, res.dist
 
     batch_spec = P(axis_names)

@@ -19,8 +19,8 @@ over a default service; this module owns the per-lane *device steps*:
 
 * ``serve_step`` — the general lane: label gather -> sketch (Eq. 3
   min-plus on the Pallas kernel when ``use_pallas=True``, the default) ->
-  vmapped guided search -> device-side edge-mask symmetrization through
-  the precomputed reverse-edge map.
+  guided search of the chunk (``search.search_chunk``) -> device-side
+  edge-mask symmetrization through the precomputed reverse-edge map.
 * ``landmark_pair_step`` / ``landmark_onesided_step`` — the vectorized
   landmark lanes.  Queries whose endpoint *is* a landmark have no label
   entries and no presence in G-, but their distance is exact from label
@@ -44,6 +44,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import cached_property, lru_cache, partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -71,8 +72,8 @@ from .packing import (
 )
 from .search import (
     Query,
-    guided_search,
     make_search_context,
+    search_chunk,
 )
 from .sketch import compute_sketch_batch
 from ..tracing import scope
@@ -99,6 +100,26 @@ class SPGResult:
         out = set(map(int, s)) | set(map(int, d))
         if self.dist == 0:
             out |= {self.u}
+        return out
+
+
+class _LaneChunk(NamedTuple):
+    dist: jax.Array        # (B,) int32
+    edge_mask: jax.Array   # (B, E) bool, symmetrized
+
+
+class GeneralChunk(_LaneChunk):
+    """A general-lane chunk's device results: unpacks, flattens and
+    copies to the host as ``(dist, edge_mask)`` like every lane step's,
+    and carries ``recover_jobs``, the () int32 count of jobs recover ran
+    (``search.recover_chunk``), which ``serving.service.fetch_chunk``
+    adds to ``stats["recover_jobs"]``.  An attribute, not a field, so the
+    pair's contract is unchanged; a rebuilt copy (``jax.device_get``)
+    holds ``None``."""
+
+    def __new__(cls, dist, edge_mask, recover_jobs=None):
+        out = super().__new__(cls, dist, edge_mask)
+        out.recover_jobs = recover_jobs
         return out
 
 
@@ -196,11 +217,6 @@ def _make_search_batch(n_vertices: int, max_levels: int, max_chain: int,
     """General-lane search program, cached on its static configuration so
     epoch-advanced indexes (``apply_update`` — same V/E capacity, new
     tables) reuse the compiled program instead of re-jitting per index."""
-    searcher = partial(
-        guided_search, n_vertices=n_vertices,
-        max_levels=max_levels, max_chain=max_chain,
-    )
-
     def search_batch(ctx, label_dist, meta_w, meta_dist, us, vs):
         # gather the *packed* rows from HBM; compute_sketch_batch
         # widens them (and the packed meta tables) in registers
@@ -215,8 +231,8 @@ def _make_search_batch(n_vertices: int, max_levels: int, max_chain: int,
             meta_edge=sk.meta_edge,
             d_star_u=sk.d_star_u, d_star_v=sk.d_star_v,
         )
-        res = jax.vmap(searcher, in_axes=(None, 0))(ctx, queries)
-        return res.dist, res.edge_mask
+        res = search_chunk(ctx, queries, n_vertices, max_levels, max_chain)
+        return res.dist, res.edge_mask, res.recover_jobs
 
     # Chained with the module-level _symmetrize program in serve_step:
     # two jit dispatches, everything on device, no host sync (see
@@ -330,15 +346,16 @@ class QbSIndex:
         """The general lane: one fixed-shape query chunk through sketch +
         guided search + edge-mask symmetrization.  Takes int32 device/host
         arrays ``(us, vs)`` of any fixed shape (B,) and returns device
-        arrays ``(dist (B,), edge_mask (B, E) bool)`` with no host sync.
+        arrays ``(dist (B,), edge_mask (B, E) bool)`` with no host sync
+        (a ``GeneralChunk``, which also carries the recover job count).
         Public contract re-exported by ``repro.serving.make_spg_serve_step``;
         landmark-endpoint lanes are garbage here — the planner routes them
         to the landmark lane steps below."""
-        d, m = self._search_batch(
+        d, m, jobs = self._search_batch(
             self.ctx, self.packed.label_dist, self.packed.meta_w,
             self.packed.meta_dist, us, vs,
         )
-        return _symmetrize(d, m, self._rev_edge_j)
+        return GeneralChunk(*_symmetrize(d, m, self._rev_edge_j), jobs)
 
     def landmark_pair_step(self, ru, rv):
         """Landmark-landmark lane step: (B,) landmark-index pairs ->

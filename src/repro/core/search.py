@@ -20,7 +20,9 @@ TPU adaptation notes (see DESIGN.md §2):
   search at all: both endpoints of an edge carry label certificates, so
   Delta is one min-plus contraction over the sketch's meta edges.
 
-Everything is fixed-shape and vmap-able over a query batch.
+Everything is fixed-shape.  BFS and reverse are per query, vmapped over
+the chunk; recover runs once per chunk over the (query, landmark) jobs
+the sketches name (``recover_chunk``).
 """
 from __future__ import annotations
 
@@ -108,11 +110,14 @@ class Query(NamedTuple):
 
 
 class SearchResult(NamedTuple):
-    edge_mask: jax.Array  # (E,) bool, path-direction orientation marks
-    dist: jax.Array       # () int32, INF if disconnected
-    d_minus: jax.Array    # () int32 d_{G-}(u, v), INF if balls never met
-    d_u: jax.Array        # () int32 explored radius, u side
-    d_v: jax.Array        # () int32 explored radius, v side
+    """A chunk's answers (leading axis = the chunk's queries)."""
+
+    edge_mask: jax.Array     # (B, E) bool, path-direction orientation marks
+    dist: jax.Array          # (B,) int32, INF if disconnected
+    d_minus: jax.Array       # (B,) int32 d_{G-}(u, v), INF if balls never met
+    d_u: jax.Array           # (B,) int32 explored radius, u side
+    d_v: jax.Array           # (B,) int32 explored radius, v side
+    recover_jobs: jax.Array  # () int32 side + meta-row jobs recover ran
 
 
 # ---------------------------------------------------------------------------
@@ -231,11 +236,15 @@ def reverse_search(ctx: SearchContext, depth_u, depth_v, d_minus, n_vertices: in
 # Stage 3: recover search  (Alg. 4 lines 18-24)
 # ---------------------------------------------------------------------------
 #
-# Every certificate below is a per-landmark test OR-ed (or min-ed) over the
-# landmarks, so recover walks the landmarks one at a time and keeps only
-# (V,) / (E,) state per query.  The (V, R) / (E, R) forms of the same tests
-# do not fit a chip at deployment size: vmapped over a 32-query chunk, one
-# (E, R) int32 temporary of a 6.6M-slot graph is 17 GB before tiling.
+# Every certificate below is a test of one landmark OR-ed over the
+# landmarks, and most (query, landmark) pairs cannot contribute: a side
+# attaches only through a finite sketch edge, and the (iii) terms only
+# through the sketch's meta edges.  So recover runs once per chunk over a
+# compacted list of the jobs the sketch names, B at a time (B = the
+# chunk's width), and keeps (J, V) / (J, E) state per block of J = B
+# jobs.  The (V, R) / (E, R) forms of the same tests do not fit a chip at
+# deployment size: over a 32-query chunk, one (E, R) int32 temporary of a
+# 6.6M-slot graph is 17 GB before tiling.
 
 
 def _label_col(ctx: SearchContext, k):
@@ -281,94 +290,170 @@ def _side_attach(ctx: SearchContext, depth, sigma, ld, dec, inc, k,
     return interior | hop_in | hop_out
 
 
-def recover_search(ctx: SearchContext, q: Query, depth_u, depth_v,
-                   max_chain: int):
-    """Components (i)/(ii) on both sides plus (iii): edges on landmark-free
-    shortest r_i - r_j paths for every meta edge in the sketch (the
-    paper's precomputed Delta), derived from labels alone.
+def _label_cols(ctx: SearchContext, ks):
+    """(J, V) int32 labels of landmarks ``ks`` (J,), widened from the
+    packed columns."""
+    return widen_dist(jnp.take(ctx.label_dist, ks, axis=1).T)
 
-    For a G- edge (x, y), (iii) holds iff some sketch meta edge (i, j) has
-        ld[x,i] + 1 + ld[y,j] == w[i,j]
-    By the triangle inequality ld[x,i] + ld[y,j] - w[i,j] >= -1, so the
-    existential test is  min_{i,j} masked(ld[x,i] + ld[y,j] - w[i,j]) == -1,
-    taken one landmark i at a time through
-        t_i[y] = min_j ( ld[y, j] + (-w[i, j] | INF) ).
-    """
-    r = ctx.label_dist.shape[1]
+
+def _compact(active):
+    """Flat positions of ``active``'s True entries first, in their order
+    (a stable sort, no scatter), and how many there are."""
+    order = jnp.argsort(~active, stable=True).astype(jnp.int32)
+    return order, jnp.sum(active, dtype=jnp.int32)
+
+
+def _or_by_query(masks, rows, valid, n_rows: int):
+    """OR the jobs' (J, E) ``masks`` into their queries' rows: (n_rows, E).
+    A one-hot (n_rows, J) x (J, E) contraction on the MXU, exact in bf16
+    (0/1 in, counts <= J accumulated in f32); a scatter by row would
+    serialize on the chip."""
+    onehot = (rows[None, :] == jnp.arange(n_rows)[:, None]) & valid[None, :]
+    hits = jnp.einsum("bj,je->be", onehot.astype(jnp.bfloat16),
+                      masks.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    return hits > 0
+
+
+def _for_blocks(block, order, n, width: int, acc):
+    """OR ``block(jobs, valid)`` into ``acc`` for the compacted job list
+    ``order[:n]``, ``width`` jobs at a time: ceil(n / width) trips.  The
+    last block's tail holds inactive positions, flagged by ``valid``."""
+    lane = jnp.arange(width)
+
+    def cond(c):
+        return c[0] < n
+
+    def body(c):
+        start, acc = c
+        jobs = jax.lax.dynamic_slice_in_dim(order, start, width)
+        return start + width, acc | block(jobs, start + lane < n)
+
+    return jax.lax.while_loop(cond, body, (n * 0, acc))[1]
+
+
+def recover_chunk(ctx: SearchContext, queries: Query, depth_u, depth_v,
+                  recover_on, max_chain: int):
+    """Recover (components (i)/(ii) on both sides and (iii)) for a whole
+    chunk: ``(edge_mask (B, E), jobs ())``.  ``queries`` and the (B, V)
+    BFS depths carry the chunk's batch axis; only rows with
+    ``recover_on`` get edges.
+
+    *Side jobs* ``(b, k, side)``: ``recover_on[b]`` and sigma_S(u_b, r_k)
+    (or sigma_S(v_b, r_k)) finite.  Each attaches the landmark-free
+    shortest paths of that sketch edge (``_side_attach``); every other
+    pair's certificate is empty, since it needs ``sigma < INF``.
+
+    *Meta-row jobs* ``(b, i)``: ``recover_on[b]`` and row i of the
+    query's meta edges non-empty.  (iii) marks the edges on landmark-free
+    shortest r_i - r_j paths of every sketch meta edge (i, j) (the
+    paper's precomputed Delta), from labels alone.  For a G- edge (x, y)
+    it holds iff some meta edge has  ld[x,i] + 1 + ld[y,j] == w[i,j].
+    By the triangle inequality ld[x,i] + ld[y,j] - w[i,j] >= -1, so for
+    row i the test is  ld[x,i] + t_i[y] == -1  with
+        t_i[y] = min_j ( ld[y, j] - w[i, j]  over the row's meta edges ),
+    and the OR of the rows' tests equals the min over all meta edges
+    being -1.  The boundary hops r_i -> y and x -> r_j and the direct
+    r_i - r_j edges of weight 1 complete the row.
+
+    Each list is compacted on the device and run in blocks of B jobs; the
+    bound is the dense form's pass count (2R side and R meta blocks when
+    every entry is finite).  Block masks OR into their queries' rows by
+    ``_or_by_query``.  ``jobs`` counts both lists."""
+    b, r = queries.du_land.shape
     w = widen_dist(ctx.meta_w)
-    fin = (w < INF) & q.meta_edge
-    m2 = jnp.where(fin, -w, INF).astype(jnp.int32)   # (i, j)
-    g1 = jnp.where(fin, w - 1, -1)                   # (i, j)
-    lid_src = jnp.clip(ctx.lid[ctx.src], 0, None)
-    lid_dst = jnp.clip(ctx.lid[ctx.dst], 0, None)
+    fin = (w < INF)[None] & queries.meta_edge                 # (B, R, R)
+    land = jnp.stack([queries.du_land, queries.dv_land], axis=-1)
+    side_order, n_side = _compact(
+        (recover_on[:, None, None] & (land < INF)).reshape(-1))
+    meta_order, n_meta = _compact(
+        (recover_on[:, None] & fin.any(axis=2)).reshape(-1))
+    land = land.reshape(-1)
     # loop carries start from query data so that, under a shard_map, their
     # varying-axes type matches the per-query values they accumulate
-    zero = q.u * 0
-    big = jnp.int32(1 << 30) + zero   # above any finite or INF-saturated sum
+    big = jnp.int32(1 << 30) + n_side * 0   # above any finite or INF sum
+    lid_src = ctx.lid[ctx.src]
+    lid_dst = jnp.clip(ctx.lid[ctx.dst], 0, None)
+    lm_src, lm_dst = ctx.is_landmark[ctx.src], ctx.is_landmark[ctx.dst]
 
-    def per_landmark(k, c):
-        edges, minval, hop_a, hop_b = c
-        ld = _label_col(ctx, k)
-        ls, ldd = ld[ctx.src], ld[ctx.dst]
+    def side_block(jobs, valid):
+        qb, k, on_v = jobs // (2 * r), (jobs // 2) % r, jobs % 2 == 1
+        depth = jnp.where(on_v[:, None], depth_v[qb], depth_u[qb])
+        sigma = jnp.where(valid, land[jobs], INF)
+        ld = _label_cols(ctx, k)
+        ls, ldd = ld[:, ctx.src], ld[:, ctx.dst]
         dec = ctx.gminus_e & (ldd < INF) & (ldd == ls - 1)
         inc = ctx.gminus_e & (ls < INF) & (ls == ldd - 1)
-        edges = edges | _side_attach(ctx, depth_u, q.du_land[k], ld, dec, inc,
-                                     k, max_chain)
-        edges = edges | _side_attach(ctx, depth_v, q.dv_land[k], ld, dec, inc,
-                                     k, max_chain)
+        edges = jax.vmap(
+            lambda *a: _side_attach(ctx, *a, max_chain=max_chain)
+        )(depth, sigma, ld, dec, inc, k)
+        return _or_by_query(edges, qb, valid, b)
 
-        # (iii) interior, landmark i = k
-        def t_step(j, t):
-            return jnp.minimum(t, _label_col(ctx, j) + m2[k, j])
+    def meta_block(jobs, valid):
+        qb, i = jobs // r, jobs % r
+        row = fin[qb, i]                                      # (J, R)
+        w_row = w[i]
 
-        t_k = jax.lax.fori_loop(0, r, t_step, jnp.full(ld.shape, big))
-        minval = jnp.minimum(minval, ls + t_k[ctx.dst])
-        # (iii) boundary hops r_i -> y (ld[y, j] == w[i,j] - 1) and
-        # x -> r_j, column j = k
-        hop_a = hop_a | (ldd == g1[lid_src, k])
-        hop_b = hop_b | (ls == g1[k, lid_dst])
-        return edges, minval, hop_a, hop_b
+        def column(j, c):
+            t, near = c
+            ld_j = _label_col(ctx, j)[None]
+            on = row[:, j, None]
+            w_j = w_row[:, j, None]
+            return (jnp.minimum(t, jnp.where(on, ld_j - w_j, big)),
+                    near | (on & (ld_j == w_j - 1)))
 
-    false_e = ctx.gminus_e & (zero != 0)
-    init = (false_e, jnp.full(ctx.src.shape, big), false_e, false_e)
-    edges, minval, hop_a, hop_b = jax.lax.fori_loop(0, r, per_landmark, init)
+        t0 = jnp.full((jobs.shape[0], ctx.is_landmark.shape[0]), big)
+        t, near = jax.lax.fori_loop(0, r, column, (t0, t0 < 0))
+        ls = _label_cols(ctx, i)[:, ctx.src]
+        from_ri = lid_src[None] == i[:, None]
+        interior = ctx.gminus_e & (ls + t[:, ctx.dst] == -1)
+        # boundary hops r_i -> y (ld[y, j] == w[i,j] - 1) and x -> r_j
+        # (ld[x, i] == w[i,j] - 1), then the direct r_i - r_j edges
+        hop_a = from_ri & ~lm_dst & near[:, ctx.dst]
+        hop_b = lm_dst & ~lm_src & (
+            ls == jnp.where(row, w_row - 1, -1)[:, lid_dst])
+        direct = from_ri & lm_dst & (row & (w_row == 1))[:, lid_dst]
+        edges = interior | hop_a | hop_b | direct
+        return _or_by_query(edges, qb, valid, b)
 
-    lm_src = ctx.is_landmark[ctx.src]
-    lm_dst = ctx.is_landmark[ctx.dst]
-    interior = ctx.gminus_e & (minval == -1)
-    hops = (lm_src & ~lm_dst & hop_a) | (lm_dst & ~lm_src & hop_b)
-    # Direct landmark-landmark sketch edges of weight 1.
-    direct = lm_src & lm_dst & q.meta_edge[lid_src, lid_dst] & (
-        w[lid_src, lid_dst] == 1)
-    return edges | interior | hops | direct
+    edges = recover_on[:, None] & (ctx.src < 0)[None]       # all False
+    edges = _for_blocks(side_block, side_order, n_side, b, edges)
+    edges = _for_blocks(meta_block, meta_order, n_meta, b, edges)
+    return edges, n_side + n_meta
 
 
 # ---------------------------------------------------------------------------
-# Full guided search for one query
+# Guided search of one query chunk
 # ---------------------------------------------------------------------------
 
-def guided_search(ctx: SearchContext, q: Query, n_vertices: int,
-                  max_levels: int = 64, max_chain: int = 64) -> SearchResult:
-    with scope("qbs.bfs"):
-        depth_u, depth_v, d_u, d_v, _, _, met = bidirectional_bfs(
-            ctx, q, n_vertices, max_levels
-        )
+def search_chunk(ctx: SearchContext, queries: Query, n_vertices: int,
+                 max_levels: int = 64, max_chain: int = 64) -> SearchResult:
+    """Guided search for a chunk of queries (``queries`` carries the batch
+    axis B): BFS and reverse per query, vmapped; recover once for the
+    chunk over the jobs its sketches name (``recover_chunk``)."""
 
-    common = (depth_u < INF) & (depth_v < INF)
-    sums = jnp.where(common, depth_u + depth_v, INF)
-    d_minus = jnp.min(sums)
+    def per_query(q):
+        with scope("qbs.bfs"):
+            depth_u, depth_v, d_u, d_v, _, _, met = bidirectional_bfs(
+                ctx, q, n_vertices, max_levels
+            )
+        common = (depth_u < INF) & (depth_v < INF)
+        sums = jnp.where(common, depth_u + depth_v, INF)
+        d_minus = jnp.min(sums)
+        with scope("qbs.reverse"):
+            e_rev = reverse_search(ctx, depth_u, depth_v, d_minus, n_vertices)
+        e_rev = e_rev & met & (d_minus <= q.d_top)
+        return depth_u, depth_v, e_rev, d_minus, d_u, d_v
 
-    dist = jnp.minimum(d_minus, q.d_top)
-    reverse_on = met & (d_minus <= q.d_top)
-    recover_on = (q.d_top < INF) & (q.d_top <= d_minus)
-
-    with scope("qbs.reverse"):
-        e_rev = reverse_search(ctx, depth_u, depth_v, d_minus, n_vertices)
+    depth_u, depth_v, e_rev, d_minus, d_u, d_v = jax.vmap(per_query)(queries)
+    recover_on = (queries.d_top < INF) & (queries.d_top <= d_minus)
     with scope("qbs.recover"):
-        e_rec = recover_search(ctx, q, depth_u, depth_v, max_chain)
+        e_rec, jobs = recover_chunk(ctx, queries, depth_u, depth_v,
+                                    recover_on, max_chain)
 
-    trivial = q.u == q.v
-    edge_mask = ((e_rev & reverse_on) | (e_rec & recover_on)) & ~trivial
-    dist = jnp.where(trivial, 0, dist)
+    trivial = queries.u == queries.v
+    edge_mask = (e_rev | e_rec) & ~trivial[:, None]
+    dist = jnp.where(trivial, 0, jnp.minimum(d_minus, queries.d_top))
     return SearchResult(edge_mask=edge_mask, dist=dist.astype(jnp.int32),
-                        d_minus=d_minus.astype(jnp.int32), d_u=d_u, d_v=d_v)
+                        d_minus=d_minus.astype(jnp.int32), d_u=d_u, d_v=d_v,
+                        recover_jobs=jobs)

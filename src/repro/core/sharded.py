@@ -298,8 +298,9 @@ def make_sharded_general_step(
 
         # ---- E2: Delta edges (fully local), one landmark i at a time -------
         # min_{i,j} ld[x,i] + ld[y,j] - w[i,j] == -1 through the per-vertex
-        # t_i[y] = min_j ld[y,j] + (-w[i,j] | INF), as in search.recover_search:
-        # the (E, R, R) per-query form does not fit a chip at real size
+        # t_i[y] = min_j ld[y,j] + (-w[i,j] | INF), as search.recover_chunk's
+        # meta-row jobs take it: the (E, R, R) per-query form does not fit a chip
+        # at real size
         meta_w32 = widen_dist(meta_w_p)
         w32 = jnp.where(meta_w32 < INF, meta_w32, INF)
         fin = sk.meta_edge & (meta_w32 < INF)[None]              # (B, R, R)

@@ -331,11 +331,8 @@ def lower_qbs_serve_cell(graph_name: str, mesh, *, batch: int | None = None,
 
     axis_names = tuple(mesh.axis_names)
 
-    from functools import partial
-    from ..core.search import Query, guided_search
+    from ..core.search import Query, search_chunk
     from ..core.sketch import compute_sketch_batch
-
-    searcher = partial(guided_search, n_vertices=v, max_levels=32, max_chain=32)
 
     def step(ctx, label_dist, meta_w, meta_dist, us, vs):
         lu = label_dist[us]
@@ -344,7 +341,7 @@ def lower_qbs_serve_cell(graph_name: str, mesh, *, batch: int | None = None,
         queries = Query(u=us, v=vs, d_top=sk.d_top, du_land=sk.du_land,
                         dv_land=sk.dv_land, meta_edge=sk.meta_edge,
                         d_star_u=sk.d_star_u, d_star_v=sk.d_star_v)
-        res = jax.vmap(searcher, in_axes=(None, 0))(ctx, queries)
+        res = search_chunk(ctx, queries, v, max_levels=32, max_chain=32)
         return res.edge_mask, res.dist
 
     rep = _ns(mesh, P())

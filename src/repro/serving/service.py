@@ -78,13 +78,18 @@ def _launch(step: Callable, *host_args):
 
 def fetch_chunk(out, stats: dict) -> tuple[np.ndarray, np.ndarray]:
     """Wait for a dispatched chunk ``(dist, edge_mask)``, then copy it to
-    the host; the copy's bytes add to ``stats["result_bytes"]``."""
+    the host; the copy's bytes add to ``stats["result_bytes"]``.  A
+    general-lane chunk's recover job count (``core.qbs.GeneralChunk``)
+    adds to ``stats["recover_jobs"]``."""
     nbytes = sum(x.nbytes for x in out)
+    jobs = getattr(out, "recover_jobs", None)
     with span("qbs.service.device_wait"):
         jax.block_until_ready(out)
     with span("qbs.service.fetch", bytes=nbytes):
         d, m = jax.device_get(out)
     stats["result_bytes"] += nbytes
+    if jobs is not None:
+        stats["recover_jobs"] += int(jobs)
     return d, m
 
 
@@ -323,9 +328,11 @@ class ServingService:
         self.lane_served = [0] * N_LANES   # unique pairs answered per lane
         # service-level counters (the scheduler's stats live on the
         # streaming layer); chunk_roundings counts admission-time widths
-        # rounded up to the shard multiple (warned once, counted always)
+        # rounded up to the shard multiple (warned once, counted always);
+        # recover_jobs counts the general lane's recover jobs (not on the
+        # batch-sharded path, whose step returns no count)
         self.stats = {"chunk_roundings": 0, "installs": 0,
-                      "result_bytes": 0}
+                      "result_bytes": 0, "recover_jobs": 0}
         self._warned_rounding = False
 
         if (mesh is not None or devices is not None) and getattr(

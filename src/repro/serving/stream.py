@@ -301,6 +301,7 @@ class StreamingService:
             **dict(zip(("edge_slot_capacity", "edge_slots_live"),
                        _edge_slots(self.index))),
             "result_bytes": 0,     # chunk results copied device -> host
+            "recover_jobs": 0,     # general-lane recover jobs (fetch_chunk)
         }, what="StreamingService.stats")
         # installed epochs' update infos whose path the device has not
         # settled yet (``count_updates``)

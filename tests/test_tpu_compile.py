@@ -148,8 +148,21 @@ def test_onesided_lane_loop_has_no_scatter(one_chip, no_persistent_cache,
 def _general_loop_scatters(text: str) -> set[str]:
     for s in ("qbs.bfs", "qbs.reverse", "qbs.recover"):
         assert s in text, s
+    _assert_recover_has_no_scatter(text)
     return {s for s in ("qbs.bfs", "qbs.reverse", "qbs.recover")
             if any(s in n for n in _loop_scatters(text))}
+
+
+def _assert_recover_has_no_scatter(text: str) -> None:
+    """Recover holds no scatter on any backend, in or out of its loops:
+    the job compaction is a sort, the blocks' OR into their queries a
+    one-hot contraction, and the anchor chain a row pull."""
+    names = [n for n in re.findall(r'op_name="([^"]*)"', text)
+             if "qbs.recover" in n]
+    assert any(n.endswith("/sort") for n in names), "no job compaction"
+    assert any("/while/body/bj,je->be/dot_general" in n for n in names), \
+        "no one-hot OR in the block loop"
+    assert not [n for n in names if "scatter" in n], names
 
 
 @pytest.mark.parametrize("backend", sorted(LOOP_BACKENDS))
