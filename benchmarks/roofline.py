@@ -1,16 +1,13 @@
-"""Roofline analysis from the dry-run's compiled artifacts.
+"""Roofline terms of the QbS dry-run cells (``repro.launch.dryrun``).
 
-Per (arch x shape) cell on the single-pod mesh:
+Per cell on the chosen mesh, from the compiled program's HLO summary:
 
     compute term    = HLO_FLOPs_per_device / 197e12      (bf16 peak, v5e)
     memory term     = HLO_bytes_per_device / 819e9       (HBM bw)
     collective term = collective_bytes_per_device / 50e9 (ICI link bw)
 
-FLOPs/bytes/collective-bytes come from the depth-extrapolated probes (XLA's
-HloCostAnalysis visits scan bodies once; see launch/dryrun.py); memory
-footprints come from the real-depth compile.  MODEL_FLOPS = 6*N*D (train) /
-2*N*D (inference) with N_active for MoE — the usefulness ratio flags
-remat/redundancy waste.
+XLA's cost analysis visits a while-loop body once, so each term is one
+BFS level's.
 
   PYTHONPATH=src python -m benchmarks.roofline [--mesh single] [--md out.md]
 """
@@ -28,122 +25,11 @@ LINK_BW = 50e9            # B/s / ICI link
 RESULTS = Path(__file__).resolve().parents[1] / "results" / "dryrun"
 
 
-def _param_count(cfg) -> tuple[float, float]:
-    """(total params, active params) analytically from the config."""
-    d, f, v, l = cfg.d_model, cfg.d_ff, cfg.vocab_size, cfg.n_layers
-    hd = cfg.hd
-    attn = d * hd * (cfg.n_heads + 2 * cfg.n_kv_heads) + cfg.n_heads * hd * d
-    if cfg.family == "ssm":  # rwkv6: 4 d^2 timemix + d*f*2 + d^2 channelmix
-        per_layer = 5 * d * d + 2 * d * f
-        total = l * per_layer + 2 * v * d
-        return total, total
-    mlp = 3 * d * f
-    if cfg.moe_experts:
-        dense_part = attn
-        expert_part = cfg.moe_experts * mlp
-        active_part = cfg.moe_top_k * mlp
-        total = l * (dense_part + expert_part) + 2 * v * d
-        active = l * (dense_part + active_part) + 2 * v * d
-        return total, active
-    if cfg.family == "hybrid":
-        d_in = d * cfg.ssm_expand
-        n = cfg.ssm_state
-        heads = cfg.ssm_heads or max(1, d_in // 64)
-        mamba = d * (2 * d_in + 2 * n * heads + heads) + d_in * d
-        shared = 2 * d * d + attn + mlp + d * d
-        total = l * mamba + shared + 2 * v * d
-        return total, total
-    total = l * (attn + mlp) + 2 * v * d
-    return total, total
-
-
-def _tokens(shape) -> int:
-    if shape.kind == "train" or shape.kind == "prefill":
-        return shape.global_batch * shape.seq_len
-    return shape.global_batch  # decode: one token per sequence
-
-
-def analyze_cell(name: str, data: dict, cfg, shape, n_chips: int) -> dict | None:
-    if "error" in data or "skipped" in data:
-        return None
-    ext = data.get("depth_extrapolated", {})
-    flops = ext.get("flops", data["flops"])
-    bytes_acc = ext.get("bytes_accessed", data["bytes_accessed"])
-    coll = ext.get("collectives", {k: v for k, v in data["collectives"].items()
-                                   if k != "_counts"})
-    coll_bytes = float(sum(coll.values()))
-
-    t_compute = flops / PEAK_FLOPS
-    t_memory = bytes_acc / HBM_BW
-    t_coll = coll_bytes / LINK_BW
-    terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
-    dominant = max(terms, key=terms.get)  # type: ignore[arg-type]
-
-    total, active = _param_count(cfg)
-    mult = 6 if shape.kind == "train" else 2
-    model_flops = mult * active * _tokens(shape)
-    hlo_global = flops * n_chips
-    ratio = model_flops / hlo_global if hlo_global else 0.0
-
-    bound = max(terms.values())
-    # roofline fraction: useful model flops vs what the dominant term's time
-    # would allow at peak
-    step_time = bound
-    achievable = model_flops / n_chips / PEAK_FLOPS
-    frac = achievable / step_time if step_time > 0 else 0.0
-
-    notes = {
-        "compute": "compute-bound: cut non-model FLOPs (remat policy, fused "
-                   "attention, avoid fp32 softmax up-casts)",
-        "memory": "HBM-bound: fuse elementwise chains, int8 KV cache, "
-                  "larger per-step tiles to lift arithmetic intensity",
-        "collective": "ICI-bound: reduce-scatter+all-gather decomposition, "
-                      "bf16/int8 compressed grads, overlap collectives "
-                      "with per-layer compute",
-    }
-    return {
-        "cell": name,
-        "t_compute_s": t_compute,
-        "t_memory_s": t_memory,
-        "t_collective_s": t_coll,
-        "dominant": dominant,
-        "model_flops": model_flops,
-        "hlo_flops_global": hlo_global,
-        "useful_ratio": ratio,
-        "roofline_fraction": min(frac, 1.0),
-        "collective_bytes": coll_bytes,
-        "note": notes[dominant],
-    }
-
-
-def collect(mesh: str = "single", variant: str = "") -> list[dict]:
-    import sys
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
-    from repro.configs import ARCHS
-    from repro.models import SHAPES
-
-    rows = []
-    n_chips = 256 if mesh == "single" else 512
-    for arch in sorted(ARCHS):
-        for shape_name, shape in SHAPES.items():
-            suffix = f"__{variant}" if variant else ""
-            p = RESULTS / f"lm__{arch}__{shape_name}__{mesh}{suffix}.json"
-            if not p.exists():
-                continue
-            data = json.loads(p.read_text())
-            row = analyze_cell(f"{arch}/{shape_name}", data, ARCHS[arch],
-                               shape, n_chips)
-            if row:
-                rows.append(row)
-    return rows
-
-
 def qbs_rows(mesh: str = "single") -> list[dict]:
     rows = []
-    n_chips = 256 if mesh == "single" else 512
     for p in sorted(RESULTS.glob(f"qbs-*__*__{mesh}.json")):
         data = json.loads(p.read_text())
-        if "error" in data or "skipped" in data:
+        if "error" in data:
             continue
         coll = {k: v for k, v in data["collectives"].items() if k != "_counts"}
         cb = float(sum(coll.values()))
@@ -168,15 +54,12 @@ def qbs_rows(mesh: str = "single") -> list[dict]:
 
 def to_markdown(rows: list[dict], title: str) -> str:
     out = [f"### {title}", "",
-           "| cell | compute (s) | memory (s) | collective (s) | dominant | "
-           "useful ratio | roofline frac | note |",
-           "|---|---|---|---|---|---|---|---|"]
+           "| cell | compute (s) | memory (s) | collective (s) | dominant | note |",
+           "|---|---|---|---|---|---|"]
     for r in rows:
         out.append(
             f"| {r['cell']} | {r['t_compute_s']:.3e} | {r['t_memory_s']:.3e} "
-            f"| {r['t_collective_s']:.3e} | **{r['dominant']}** "
-            f"| {r.get('useful_ratio', float('nan')):.2f} "
-            f"| {r.get('roofline_fraction', float('nan')):.2f} | {r['note']} |")
+            f"| {r['t_collective_s']:.3e} | **{r['dominant']}** | {r['note']} |")
     return "\n".join(out) + "\n"
 
 
@@ -278,18 +161,12 @@ def run(scale: float = 1.0, **_) -> list[tuple]:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="single")
-    ap.add_argument("--variant", default="")
     ap.add_argument("--md", default=None)
     args = ap.parse_args()
-    rows = collect(args.mesh, args.variant)
-    qrows = qbs_rows(args.mesh)
-    md = to_markdown(rows, f"LM cells ({args.mesh}-pod)")
-    md += "\n" + to_markdown(qrows, f"QbS engine cells ({args.mesh}-pod)")
+    md = to_markdown(qbs_rows(args.mesh), f"QbS engine cells ({args.mesh}-pod)")
     if args.md:
         Path(args.md).write_text(md)
     print(md)
-    for r in rows:
-        print(f"{r['cell']},{r['dominant']},{r['roofline_fraction']:.3f}")
 
 
 if __name__ == "__main__":

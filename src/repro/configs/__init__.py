@@ -1,32 +1,1 @@
-"""Assigned architecture pool: exact public configs, selectable via
-``--arch <id>`` in the launchers.  Sources/verification tiers per the brief
-are recorded in each module's docstring."""
-from __future__ import annotations
-
-from ..models.config import ModelConfig
-from . import (
-    dbrx_132b,
-    deepseek_7b,
-    hubert_xlarge,
-    internvl2_76b,
-    phi3_medium_14b,
-    phi3p5_moe_42b,
-    qwen1p5_32b,
-    qwen1p5_4b,
-    rwkv6_1p6b,
-    zamba2_2p7b,
-)
-
-ARCHS: dict[str, ModelConfig] = {
-    m.CONFIG.name: m.CONFIG
-    for m in (
-        zamba2_2p7b, qwen1p5_4b, deepseek_7b, qwen1p5_32b, phi3_medium_14b,
-        phi3p5_moe_42b, dbrx_132b, rwkv6_1p6b, hubert_xlarge, internvl2_76b,
-    )
-}
-
-
-def get_config(arch: str) -> ModelConfig:
-    if arch not in ARCHS:
-        raise KeyError(f"unknown arch {arch!r}; available: {sorted(ARCHS)}")
-    return ARCHS[arch]
+"""Paper-scale graph shapes (``qbs_graphs``) for the dry-run cells."""

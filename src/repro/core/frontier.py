@@ -46,7 +46,7 @@ is static aux data), so it passes through ``jit`` / ``vmap(in_axes=None)``
 the static config.
 
 ``segment_or`` is the raw primitive; the edge-sharded shard_map programs in
-``core.distributed`` / ``core.scale_serve`` call it directly on their local
+``core.distributed`` / ``core.sharded`` call it directly on their local
 edge shards so the relay semantics live in exactly one module.
 """
 from __future__ import annotations

@@ -1,28 +1,53 @@
 """Vertex-sharded QbS index: every serving lane answered from the
-born-sharded tables (DESIGN.md §11).
+born-sharded tables (DESIGN.md §11).  This is the one vertex-sharded
+search; ``core.search`` is the replicated one.
 
 ``distributed_build_sharded`` leaves the packed label table, the (R, V)
 landmark-distance table and the CSR edge partition resident one vertex
 block per device (``jax.sharding.NamedSharding``); ``ShardedIndex`` is
 the ``QbSIndex``-shaped facade that serves from them without ever
-materializing a full table:
+materializing a full table.
 
-* **General lane** (``make_sharded_general_step``): the vertex-sharded
-  twin of ``core.scale_serve`` fed by *packed* labels.  Sketch rows for
-  (u, v) come from the owning shard (owned-else-INF + ``pmin``); the
-  sketch itself is a replicated O(B R^2) compute; the sketch-bounded
-  Bi-BFS / reverse sweeps / recover chains run the ``segment_or`` relay
-  on each device's local dst-owned edges with one packed-bitmap
-  ``all_gather`` frontier exchange per level (the halo exchange — words
-  stay packed across the wire).  Edge-source label columns are read from
-  a *transient* in-program gather of the packed table, so the resident
-  footprint stays one block per device (no edge-aligned label copies).
-* **Landmark lanes** (``make_sharded_landmark_pair_step`` /
-  ``make_sharded_onesided_step``): gather exactly the ``B`` packed rows
-  of the landmark-distance table each chunk needs (one row per query
-  side — never the table), then certify per local edge; the one-sided
-  lane adds the same distance-bounded BFS as the replicated lane,
-  sharded level-synchronously like the general lane.
+Layout (per device, under ``shard_map``; S shards over all mesh axes):
+
+  vertices  contiguous block [vstart, vstart + v_loc), +1 garbage row
+  edges     dst-owned (the ``EdgePartition`` of the distributed
+            labelling), with global slot ids and their reverse slots
+  labels    the packed (v_loc, R) block; the (R, R) meta tables and the
+            landmark list replicated
+  queries   (B,) replicated; every per-query scalar replicated via psum
+
+**General lane** (``make_sharded_general_step``).  Its phases mirror
+``core.search`` (DESIGN.md §2 gives the certificates):
+
+  A  label rows for (u, v): owned-else-INF, then a global ``pmin``
+  B  sketch: replicated compute, O(B R^2)
+  C  sketch-bounded bidirectional BFS: per level, one packed-bitmap
+     ``all_gather`` of the chosen side's frontier (the halo exchange;
+     words stay packed across the wire), then the ``segment_or`` relay
+     over the local dst-owned edges into the local depth
+  D  reverse sweep per side: one (on & depth == l) bitmap exchange per
+     level
+  E  recover: per-landmark pointwise certificates plus a fixed-length
+     chain closure (one bitmap exchange per iteration); the Delta edges
+     through the sketch's meta edges are fully edge-local
+
+Every certificate of phases D and E compares an edge's source label
+with its destination label.  The destination row is local (edges are
+dst-owned); the source row is not.  An edge-aligned copy of the source
+labels, (E_loc, R) per device, would make each certificate local, but it
+holds R label entries per edge slot: the mean degree times the device's
+own label block, resident for good.  So the step reads the source
+columns from a *transient* in-program ``all_gather`` of the packed
+table: it crosses the wire packed, widens in registers and is never
+resident, and the resident footprint stays one block per device.
+
+**Landmark lanes** (``make_sharded_landmark_pair_step`` /
+``make_sharded_onesided_step``): gather exactly the ``B`` packed rows
+of the landmark-distance table each chunk needs (one row per query
+side — never the table), then certify per local edge; the one-sided
+lane adds the same distance-bounded BFS as the replicated lane,
+sharded level-synchronously like the general lane.
 
 Every lane ends in the same **scatter-symmetrize**: each shard writes
 its locally-certified edges into the canonical ``(B, n_edges)`` mask at
@@ -30,13 +55,13 @@ their global slot *and* its reverse slot (``EdgePartition.eid`` + the
 host-built reverse map), then one ``psum`` replicates the union.  Each
 directed edge is dst-owned by exactly one shard, so this equals the
 replicated path's ``mask | mask[:, rev_edge]`` bit-for-bit — pinned by
-tests/test_sharded_index.py against the replicated oracle on emulated
-8-device meshes.
+tests/test_sharded_index.py against the replicated index on a one-shard
+mesh over the replicated path's graph families, and on an emulated
+8-device mesh in a subprocess.
 
-Exactness caveat (same as ``core.scale_serve``): ``max_levels`` /
-``max_chain`` must exceed the graph's diameter / longest recover chain;
-the defaults suit the test graphs, paper-scale runs size them from the
-measured diameter.
+Exactness caveat: ``max_levels`` / ``max_chain`` must exceed the graph's
+diameter / longest recover chain; the defaults suit the test graphs,
+paper-scale runs size them from the measured diameter.
 """
 from __future__ import annotations
 
@@ -89,12 +114,10 @@ def make_sharded_general_step(
     max_levels: int = 32,
     max_chain: int = 8,
 ):
-    """General lane from vertex-sharded packed tables.  The phase
-    structure mirrors ``core.scale_serve`` (A label rows, B sketch,
-    C bounded Bi-BFS, D reverse sweeps, E recover) — see that module for
-    the certificate derivations; the differences here are packed-label
-    widening (``widen_dist`` in-program), the transient edge-source
-    label gather, and the scatter-symmetrized replicated output.
+    """General lane from vertex-sharded packed tables: phases A–E of
+    the module docstring, with packed labels widened in-program
+    (``widen_dist``), the transient edge-source label gather, and the
+    scatter-symmetrized replicated output.
 
     Inputs: sharded (src, dst_local, eid, rev_eid, vstart, nloc,
     labels_sh) + replicated (landmarks, packed meta_w/meta_dist, us, vs).

@@ -26,6 +26,3 @@ def make_production_mesh(*, multi_pod: bool = False):
         )
     return Mesh(np.array(devs[:n]).reshape(shape), axes)
 
-
-def dp_axes(mesh) -> tuple[str, ...]:
-    return tuple(n for n in mesh.axis_names if n != "model")

@@ -10,9 +10,6 @@ from .planner import (
     plan_queries,
 )
 from .serve_step import (
-    greedy_generate,
-    make_decode_step,
-    make_prefill_step,
     make_spg_serve_step,
     serve_spg_batch,
 )
@@ -45,9 +42,6 @@ __all__ = [
     "ResultCache",
     "ServingService",
     "StreamingService",
-    "greedy_generate",
-    "make_decode_step",
-    "make_prefill_step",
     "make_spg_serve_step",
     "merge_plans",
     "merged_latency",

@@ -1,39 +1,21 @@
-"""Serving steps: the QbS shortest-path-graph query pipeline and the LM
-prefill/decode units.
+"""Serving steps: the QbS shortest-path-graph query pipeline.
 
-**SPG serving** (``make_spg_serve_step`` / ``serve_spg_batch``): the
-persistent, fully-jitted batched pipeline over a built ``QbSIndex`` —
-label gather -> sketch (min-plus on the Pallas kernel when the index was
-built with ``use_pallas=True``, the default) -> vmapped guided search ->
+``make_spg_serve_step`` / ``serve_spg_batch``: the persistent,
+fully-jitted batched pipeline over a built ``QbSIndex`` — label gather
+-> sketch (min-plus on the Pallas kernel when the index was built with
+``use_pallas=True``, the default) -> vmapped guided search ->
 device-side edge-mask symmetrization.  The step is fixed-shape (``B =
 index.chunk`` lanes), returns device arrays with no host sync, and serves
 the general (non-landmark-endpoint) lane; ``serve_spg_batch`` answers
 arbitrary batches through the planner/service stack (``serving.planner``
 routes lanes, ``serving.service`` executes them with double-buffered
 async dispatch — same as ``QbSIndex.query_batch``).
-
-**LM serving**: prefill and single-token decode (the units the dry-run
-lowers for the decode_* / prefill_* shape cells), plus a simple batched
-greedy-decode driver for the examples.  KV caches support bf16 and int8
-(per-position scales, see ``models.layers``); int8 halves the decode
-memory term — the default for the 32k/500k cells where cache bytes
-dominate the roofline.
 """
 from __future__ import annotations
 
-from functools import lru_cache
 from typing import Callable
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from ..models.registry import Model, build_model
-
-
-# ---------------------------------------------------------------------------
-# QbS shortest-path-graph serving
-# ---------------------------------------------------------------------------
 
 
 def make_spg_serve_step(index) -> Callable:
@@ -73,82 +55,3 @@ def serve_spg_batch(index, us, vs) -> tuple[np.ndarray, np.ndarray]:
     ``(dist (N,) int32, edge_mask (N, E) bool)``.
     """
     return index.query_batch_arrays(us, vs)
-
-
-def make_prefill_step(model: Model):
-    def prefill_step(params, batch):
-        if model.cfg.encoder_only:
-            # encoder "prefill" = the full forward pass (no cache exists)
-            logits, _ = model.forward(params, batch=batch)
-            return logits, None
-        return model.prefill(params, batch=batch)
-
-    return prefill_step
-
-
-def make_decode_step(model: Model):
-    def decode_step(params, cache, cache_len, tokens):
-        return model.decode(params, cache=cache, cache_len=cache_len, tokens=tokens)
-
-    return decode_step
-
-
-@lru_cache(maxsize=32)
-def _generate_steps(cfg):
-    """Jitted prefill/decode pair per ``ModelConfig`` (frozen, hashable).
-    The model facade is pure functions of the config, so rebuilding it
-    here yields the same computation — and caching on the config keeps
-    one jit (and one compile cache) per architecture instead of a fresh
-    one per ``greedy_generate`` call (QBS004 recompile churn)."""
-    model = build_model(cfg)
-    return jax.jit(model.prefill), jax.jit(make_decode_step(model))
-
-
-def greedy_generate(model: Model, params, prompt_tokens, n_new: int,
-                    *, kv_quant: bool = False):
-    """Host loop driver: prefill the prompt then decode n_new tokens."""
-    b, s = prompt_tokens.shape
-    prefill, decode = _generate_steps(model.cfg)
-    logits, pre_cache = prefill(
-        params, batch={"tokens": jnp.asarray(prompt_tokens)})
-    if model.cfg.family in ("ssm",):
-        cache = pre_cache
-        cache_len = jnp.int32(s)
-    elif model.cfg.family == "hybrid":
-        # copy before any write: mutating the dict returned by
-        # init_decode_cache would alias whatever the model cached internally
-        cache = dict(model.init_decode_cache(b, s + n_new, kv_quant=kv_quant))
-        k_pre, v_pre = pre_cache["attn"]
-        k_buf, v_buf = cache["attn"]
-        cache["mamba"] = pre_cache["mamba"]
-        cache["attn"] = (
-            k_buf.at[:, :, :s].set(k_pre.astype(k_buf.dtype))
-            if not isinstance(k_buf, dict) else k_buf,
-            v_buf.at[:, :, :s].set(v_pre.astype(v_buf.dtype))
-            if not isinstance(v_buf, dict) else v_buf,
-        )
-        cache_len = jnp.int32(s)
-    else:
-        cache = model.init_decode_cache(b, s + n_new, kv_quant=kv_quant)
-        k_pre, v_pre = pre_cache["layers"]
-        k_buf, v_buf = cache["layers"]
-        if isinstance(k_buf, dict):
-            # re-prefill through the quantized path: write positions 0..s-1
-            from ..models.layers import _quant
-            kq = jax.tree_util.tree_map(lambda x: x, _quant(k_pre))
-            vq = _quant(v_pre)
-            k_buf = {"q": k_buf["q"].at[:, :, :s].set(kq["q"]),
-                     "scale": k_buf["scale"].at[:, :, :s].set(kq["scale"])}
-            v_buf = {"q": v_buf["q"].at[:, :, :s].set(vq["q"]),
-                     "scale": v_buf["scale"].at[:, :, :s].set(vq["scale"])}
-        else:
-            k_buf = k_buf.at[:, :, :s].set(k_pre.astype(k_buf.dtype))
-            v_buf = v_buf.at[:, :, :s].set(v_pre.astype(v_buf.dtype))
-        cache = {"layers": (k_buf, v_buf)}
-        cache_len = jnp.int32(s)
-
-    out = [jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)]
-    for i in range(n_new - 1):
-        logits, cache = decode(params, cache, cache_len + i, out[-1][:, None])
-        out.append(jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32))
-    return jnp.stack(out, axis=1)

@@ -1,3 +1,3 @@
 # Makes in-test imports like ``from helpers.serving_oracle import ...``
 # resolve (pytest prepends tests/ to sys.path).  dist_check.py and
-# scale_serve_check.py stay standalone subprocess scripts.
+# sharded_check.py stay standalone subprocess scripts.

@@ -22,6 +22,8 @@ from repro.core.distributed import (
     partition_edges,
 )
 
+from helpers.graph_families import FAMILIES, lane_pairs
+
 
 def test_partition_edges_covers_all_edges():
     g = gnp_random_graph(50, 4.0, seed=3)
@@ -80,6 +82,26 @@ def test_sharded_serving_single_device_mesh():
         }
         assert int(dist[k]) == o.dist
         assert pairs == o.edge_pairs(g)
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+def test_batch_sharded_serve_step_matches_replicated(family):
+    """The batch-sharded general lane, built as ``ServingService(mesh=)``
+    builds it, == ``QbSIndex.query_batch_arrays`` bit for bit."""
+    make, nl = FAMILIES[family]
+    g = make()
+    idx = QbSIndex.build(g, n_landmarks=nl, use_pallas=False)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    serve = make_serve_step(
+        idx.ctx, idx.scheme, mesh, n_vertices=g.n_vertices,
+        max_levels=idx.max_levels, max_chain=idx.max_chain,
+        use_pallas=idx.use_pallas, packed=idx.packed)
+    us, vs = lane_pairs(g, idx.scheme.landmarks, "general")
+    mask, dist = serve(us, vs)
+    mask = np.asarray(mask)
+    d_ref, m_ref = idx.query_batch_arrays(us, vs)
+    np.testing.assert_array_equal(np.asarray(dist), d_ref)
+    np.testing.assert_array_equal(mask | mask[:, idx._rev_edge], m_ref)
 
 
 @pytest.mark.slow

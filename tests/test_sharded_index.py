@@ -1,6 +1,7 @@
 """Vertex-sharded index (born-sharded labels + sharded serving) ==
-replicated ``QbSIndex``, bit for bit — single-shard in-process, 8-device
-emulated mesh in a subprocess."""
+replicated ``QbSIndex``, bit for bit — single-shard in-process over the
+replicated path's graph families and every lane, 8-device emulated mesh
+in a subprocess."""
 import os
 import subprocess
 import sys
@@ -14,6 +15,8 @@ from jax.sharding import Mesh
 from repro.core import QbSIndex, gnp_random_graph, grid_graph
 from repro.core.distributed import distributed_build_sharded
 from repro.core.sharded import ShardedIndex
+
+from helpers.graph_families import CASES, FAMILIES, lane_pairs
 
 
 def _graphs():
@@ -57,6 +60,35 @@ def test_sharded_serving_single_shard_matches_replicated():
         d_sh, m_sh = sh.query_batch_arrays(us, vs)
         np.testing.assert_array_equal(d_sh, d_ref)
         np.testing.assert_array_equal(m_sh, m_ref)
+
+
+@pytest.fixture(scope="module")
+def family_indexes():
+    """``name -> (replicated, one-shard sharded)`` index pair, built once
+    a family so the lane cases of a family share their compiles."""
+    built = {}
+
+    def get(name):
+        if name not in built:
+            make, nl = FAMILIES[name]
+            g = make()
+            ref = QbSIndex.build(g, n_landmarks=nl, use_pallas=False)
+            sh = ShardedIndex.build(
+                g, landmarks=np.asarray(ref.scheme.landmarks), mesh=1)
+            built[name] = ref, sh
+        return built[name]
+
+    return get
+
+
+@pytest.mark.parametrize("family,lane", CASES)
+def test_sharded_lane_matches_replicated(family_indexes, family, lane):
+    ref, sh = family_indexes(family)
+    us, vs = lane_pairs(ref.graph, ref.scheme.landmarks, lane)
+    d_ref, m_ref = ref.query_batch_arrays(us, vs)
+    d_sh, m_sh = sh.query_batch_arrays(us, vs)
+    np.testing.assert_array_equal(d_sh, d_ref)
+    np.testing.assert_array_equal(m_sh, m_ref)
 
 
 def test_qbs_build_sharded_kwarg_returns_sharded_index():
